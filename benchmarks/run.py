@@ -258,7 +258,6 @@ def grad_codec_allreduce():
                                an 8-leaf pytree: one collective per leaf vs
                                the single-buffer bucketed psum
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.dist.grad_codec import rns_psum, rns_psum_tree
@@ -272,8 +271,9 @@ def grad_codec_allreduce():
     rng = np.random.default_rng(7)
     for size in ALLREDUCE_SIZES:
         g = jnp.asarray(rng.standard_normal(size).astype(np.float32))
-        sm = lambda f: jax.jit(shard_map(
-            f, mesh, in_specs=P("data"), out_specs=P("data"), check_rep=False
+        sm = lambda f: jax.jit(jax.shard_map(
+            f, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+            check_vma=False,
         ))
         f_rns = sm(lambda x: rns_psum(codec_jnp, x, "data"))
         f_rns_fused = sm(lambda x: rns_psum(codec, x, "data"))
@@ -309,8 +309,8 @@ def grad_codec_allreduce():
             )
             for i in range(8)
         }
-        smt = lambda f: jax.jit(shard_map(
-            f, mesh, in_specs=(P(),), out_specs=P(), check_rep=False
+        smt = lambda f: jax.jit(jax.shard_map(
+            f, mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False
         ))
         f_leaf = smt(lambda t: jax.tree_util.tree_map(
             lambda x: rns_psum(codec_jnp, x, "data"), t))

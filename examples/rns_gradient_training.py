@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import repro  # noqa: F401
 from repro.configs import get_config
 from repro.dist.grad_codec import GradCodec
-from repro.launch.train import make_rns_dp_step
+from repro.launch.train import make_dp_step
 from repro.models import init_params
 from repro.train.data import SyntheticLM
 from repro.train.optimizer import AdamWConfig, adamw_init
@@ -29,7 +29,7 @@ codec = GradCodec.make(world=8)
 print(f"codec: {codec.base.n}+1 channels of 15-bit moduli, "
       f"M ~ 2^{codec.base.M.bit_length()}, quant step 2^-{codec.frac_bits}")
 
-rns_step, ndev = make_rns_dp_step(cfg, opt_cfg, codec)
+rns_step, _ = make_dp_step(cfg, opt_cfg, codec)
 fp_step = jax.jit(make_train_step(cfg, opt_cfg))
 loader = SyntheticLM(cfg, seq=32, batch=8, pattern="arith")
 

@@ -27,9 +27,10 @@ backend was active when it was traced, exactly like the static ``fused``
 flag on ``GradCodec``.  Re-trace (new jit, or different static args) to
 change the route of an already-compiled function.
 
-``interpret_default()`` is the single home of the "interpret off-TPU" rule
-that ``kernels/ops.py`` wrappers consult; the per-call ``interpret=``
-kwargs remain as explicit overrides for tests.
+``interpret_default()`` is the single home of the "interpret off-TPU" rule:
+every ``*_kernel_call`` resolves its ``interpret=None`` default through
+``resolve_interpret``; an explicit ``interpret=`` remains an override for
+tests.
 """
 from __future__ import annotations
 
@@ -38,7 +39,8 @@ import threading
 
 import jax
 
-__all__ = ["backend", "get_backend", "resolve_backend", "interpret_default"]
+__all__ = ["backend", "get_backend", "resolve_backend", "interpret_default",
+           "resolve_interpret"]
 
 _SETTINGS = ("jnp", "pallas", "auto")
 
@@ -71,6 +73,12 @@ def interpret_default() -> bool:
     """Pallas kernels run interpreted off-TPU (there is no Mosaic lowering
     to run); this is the ONE definition all kernel wrappers share."""
     return jax.default_backend() != "tpu"
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """An explicit ``interpret=`` wins; ``None`` means ``interpret_default()``,
+    so no kernel call on a TPU is ever interpreted unless a caller asks."""
+    return interpret_default() if interpret is None else interpret
 
 
 @contextlib.contextmanager

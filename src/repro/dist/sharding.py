@@ -25,9 +25,11 @@ from __future__ import annotations
 import math
 
 import jax
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = [
+    "auto_mesh",
     "param_specs",
     "opt_state_specs",
     "batch_specs",
@@ -258,3 +260,16 @@ def named_shardings(specs, mesh):
         lambda s: NamedSharding(mesh, s), specs,
         is_leaf=lambda x: isinstance(x, P),
     )
+
+
+def auto_mesh(shape, axes, *, devices=None):
+    """A mesh with every axis ``Auto``: the program places data with these
+    specs and ``with_sharding_constraint`` and lets the partitioner
+    propagate, which an ``Explicit`` mesh (``jax.make_mesh``'s default)
+    refuses.  Without ``devices``, ``jax.make_mesh`` lays the fleet out in
+    its physical order; given ``devices``, the mesh keeps their order."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(shape, axes, auto)
+    return jax.sharding.Mesh(np.asarray(devices).reshape(shape), axes,
+                             axis_types=auto)

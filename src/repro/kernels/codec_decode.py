@@ -27,7 +27,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import barrett_mod, mrc_rows
+from repro.core.dispatch import resolve_interpret
+
+from .common import barrett_mod, batch_block, mrc_rows, resident
 
 __all__ = ["codec_decode_kernel_call"]
 
@@ -92,7 +94,7 @@ def _kernel(x_ref, invt_ref, m_ref, half_ref, out_ref, *, n, inv_scale):
 )
 def codec_decode_kernel_call(
     x_t, inv_t, m_col, half_col, *, n: int, inv_scale: float,
-    block_b: int = 1024, interpret: bool = True,
+    block_b: int = 1024, interpret: bool | None = None,
 ):
     """x_t: (n+1, B) int32 summed channels -> (1, B) f32 gradients."""
     nch, B = x_t.shape
@@ -100,13 +102,9 @@ def codec_decode_kernel_call(
     return pl.pallas_call(
         functools.partial(_kernel, n=n, inv_scale=inv_scale),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((nch, block_b), lambda b: (0, b)),
-            pl.BlockSpec((n, n), lambda b: (0, 0)),
-            pl.BlockSpec((n, 1), lambda b: (0, 0)),
-            pl.BlockSpec((6, 1), lambda b: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_b), lambda b: (0, b)),
+        in_specs=[batch_block(nch, block_b), resident((n, n)),
+                  resident((n, 1)), resident((6, 1))],
+        out_specs=batch_block(1, block_b),
         out_shape=jax.ShapeDtypeStruct((1, B), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x_t, inv_t, m_col, half_col)

@@ -36,7 +36,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import barrett_mod
+from repro.core.dispatch import resolve_interpret
+
+from .common import barrett_mod, batch_block, resident
 
 __all__ = ["codec_encode_kernel_call"]
 
@@ -66,7 +68,8 @@ def _kernel(g_ref, m_ref, pow15_ref, off_ref, out_ref, *, scale, qh, ql):
     r_abs = barrett_mod(r_hi * pow15_ref[...] + lo, m, recip)
 
     # signed embedding: (-|q|) mod m = m - (|q| mod m), except when 0
-    res = jnp.where(neg & (r_abs > 0), m - r_abs, jnp.where(neg, 0, r_abs))
+    res = jnp.where(neg & (r_abs > 0), m - r_abs,
+                    jnp.where(neg, jnp.zeros_like(r_abs), r_abs))
 
     # redundant rows additionally shift by M mod m_r when negative: the
     # channels store q + M, so each m_r must track (q + M) mod m_r.  Base
@@ -82,7 +85,7 @@ def _kernel(g_ref, m_ref, pow15_ref, off_ref, out_ref, *, scale, qh, ql):
 )
 def codec_encode_kernel_call(
     g_row, m_all, pow15, off, *, scale: float, qh: int, ql: int,
-    block_b: int = 1024, interpret: bool = True,
+    block_b: int = 1024, interpret: bool | None = None,
 ):
     """g_row: (1, B) f32 gradients -> (nch, B) int32 packed residues, where
     nch = n base + 1 or 2 redundant channels (detect vs locate-and-correct
@@ -99,13 +102,9 @@ def codec_encode_kernel_call(
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, qh=qh, ql=ql),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_b), lambda b: (0, b)),
-            pl.BlockSpec((nch, 1), lambda b: (0, 0)),
-            pl.BlockSpec((nch, 1), lambda b: (0, 0)),
-            pl.BlockSpec((nch, 1), lambda b: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((nch, block_b), lambda b: (0, b)),
+        in_specs=[batch_block(1, block_b), resident((nch, 1)),
+                  resident((nch, 1)), resident((nch, 1))],
+        out_specs=batch_block(nch, block_b),
         out_shape=jax.ShapeDtypeStruct((nch, B), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(g_row, m_all, pow15, off)

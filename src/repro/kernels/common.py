@@ -11,13 +11,34 @@ TPU adaptation notes (DESIGN.md §3):
   t < 2**30, the f32 quotient error is < 1/2, so one conditional add and one
   conditional subtract make the result exact.  This replaces integer
   division/remainder, which the VPU lowers slowly.
+* Every value is int32 or f32, stated explicitly: ``import repro`` turns x64
+  on, and Mosaic refuses a kernel that holds a 64-bit value (a Python int
+  literal, an index-map zero, or a ``jnp.sum`` default would otherwise
+  become int64).
+* Loops over channels are static Python unrolls with static slices: ``n``
+  is a small trace-time constant, and Mosaic has no lowering for a
+  traced-index ``dynamic_slice``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
 
-__all__ = ["barrett_mod", "mrc_rows", "to_ma_rows"]
+__all__ = ["barrett_mod", "mrc_rows", "to_ma_rows", "batch_block", "resident"]
+
+_I0 = np.int32(0)
+
+
+def batch_block(rows: int, block_b: int) -> pl.BlockSpec:
+    """A (rows, block_b) tile walking the batch (lane) axis with the grid."""
+    return pl.BlockSpec((rows, block_b), lambda b: (_I0, b))
+
+
+def resident(shape) -> pl.BlockSpec:
+    """A whole small table, the same block at every grid step."""
+    return pl.BlockSpec(shape, lambda b: (_I0, _I0))
 
 
 def barrett_mod(t, m, recip):
@@ -38,16 +59,14 @@ def mrc_rows(w, inv_t, m, recip, *, n: int):
     Returns (n, B) mixed-radix digits.
     """
     idx = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
-
-    def body(j, w):
-        a_j = jax.lax.dynamic_slice_in_dim(w, j, 1, axis=0)        # (1, B)
-        inv_j = jax.lax.dynamic_slice_in_dim(inv_t, j, 1, axis=1)  # (n, 1)
+    for j in range(n - 1):
+        a_j = w[j : j + 1, :]                                      # (1, B)
+        inv_j = inv_t[:, j : j + 1]                                # (n, 1)
         d = w - a_j
         d = jnp.where(d < 0, d + m, d)
         r = barrett_mod(d * inv_j, m, recip)
-        return jnp.where(idx > j, r, w)
-
-    return jax.lax.fori_loop(0, n - 1, body, w) if n > 1 else w
+        w = jnp.where(idx > j, r, w)
+    return w
 
 
 def to_ma_rows(digits, betas, ma: int):
@@ -58,5 +77,5 @@ def to_ma_rows(digits, betas, ma: int):
     """
     recip = jnp.float32(1.0 / ma)
     terms = barrett_mod(digits * betas, jnp.int32(ma), recip)
-    s = jnp.sum(terms, axis=0, keepdims=True)  # (1, B)
+    s = jnp.sum(terms, axis=0, keepdims=True, dtype=jnp.int32)  # (1, B)
     return barrett_mod(s, jnp.int32(ma), recip)

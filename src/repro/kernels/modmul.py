@@ -11,7 +11,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import barrett_mod
+from repro.core.dispatch import resolve_interpret
+
+from .common import barrett_mod, batch_block, resident
 
 __all__ = ["modmul_kernel_call"]
 
@@ -23,19 +25,17 @@ def _kernel(x_ref, y_ref, m_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
-def modmul_kernel_call(x_t, y_t, m_col, *, block_b: int = 1024, interpret: bool = True):
+def modmul_kernel_call(x_t, y_t, m_col, *, block_b: int = 1024,
+                       interpret: bool | None = None):
     """x_t, y_t: (n, B) int32 reduced residues -> (n, B) product residues."""
     n, B = x_t.shape
     grid = (B // block_b,)
     return pl.pallas_call(
         _kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((n, block_b), lambda b: (0, b)),
-            pl.BlockSpec((n, block_b), lambda b: (0, b)),
-            pl.BlockSpec((n, 1), lambda b: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((n, block_b), lambda b: (0, b)),
+        in_specs=[batch_block(n, block_b), batch_block(n, block_b),
+                  resident((n, 1))],
+        out_specs=batch_block(n, block_b),
         out_shape=jax.ShapeDtypeStruct((n, B), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x_t, y_t, m_col)

@@ -30,7 +30,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import barrett_mod, mrc_rows
+from repro.core.dispatch import resolve_interpret
+
+from .common import barrett_mod, batch_block, mrc_rows, resident
 
 __all__ = ["mont_mul_kernel_call", "mont_ladder_kernel_call"]
 
@@ -42,15 +44,13 @@ def _dot_rows(digits, betas, m, recip, *, n: int):
     m_t; m/recip: (T, 1).  Returns (T, B) residues, each term Barrett-
     reduced so the running sum stays < 2m < 2**16.
     """
-    zero = jnp.zeros((betas.shape[0], digits.shape[1]), jnp.int32)
-
-    def body(j, acc):
-        d_j = jax.lax.dynamic_slice_in_dim(digits, j, 1, axis=0)   # (1, B)
-        b_j = jax.lax.dynamic_slice_in_dim(betas, j, 1, axis=1)    # (T, 1)
+    acc = jnp.zeros((betas.shape[0], digits.shape[1]), jnp.int32)
+    for j in range(n):
+        d_j = digits[j : j + 1, :]                                 # (1, B)
+        b_j = betas[:, j : j + 1]                                  # (T, 1)
         s = acc + barrett_mod(d_j * b_j, m, recip)
-        return jnp.where(s >= m, s - m, s)
-
-    return jax.lax.fori_loop(0, n, body, zero)
+        acc = jnp.where(s >= m, s - m, s)
+    return acc
 
 
 def _mm_tile(xlo, xhi, ylo, yhi, neg, nhi, invt_lo, m_lo, betas_l2h,
@@ -113,18 +113,18 @@ def _ladder_kernel(r0lo_ref, r0hi_ref, r1lo_ref, r1hi_ref, bit_ref,
 
 
 def _specs(nch_lo, n_lo, n_hi, block_b):
-    blk = lambda r: pl.BlockSpec((r, block_b), lambda b: (0, b))
-    tbl = lambda s: pl.BlockSpec(s, lambda b: (0, 0))
-    tables = [tbl((n_lo, n_lo)), tbl((nch_lo, 1)), tbl((n_hi, n_lo)),
-              tbl((n_hi, n_hi)), tbl((n_hi, 1)), tbl((nch_lo, n_hi)),
-              tbl((n_hi, 1))]
+    blk = functools.partial(batch_block, block_b=block_b)
+    tables = [resident(s) for s in ((n_lo, n_lo), (nch_lo, 1), (n_hi, n_lo),
+                                    (n_hi, n_hi), (n_hi, 1), (nch_lo, n_hi),
+                                    (n_hi, 1))]
     return blk, tables
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def mont_mul_kernel_call(xlo_t, xhi_t, ylo_t, yhi_t, neg_t, nhi_t,
                          invt_lo, m_lo, betas_l2h, invt_hi, m_hi, betas_h2l,
-                         minv, *, block_b: int = 256, interpret: bool = True):
+                         minv, *, block_b: int = 256,
+                         interpret: bool | None = None):
     """One batched Montgomery product; operands channel-major (rows, B).
 
     Returns ``(olo (nch_lo, B), ohi (n_hi, B))``.
@@ -140,7 +140,7 @@ def mont_mul_kernel_call(xlo_t, xhi_t, ylo_t, yhi_t, neg_t, nhi_t,
         out_specs=[blk(nch_lo), blk(n_hi)],
         out_shape=[jax.ShapeDtypeStruct((nch_lo, B), jnp.int32),
                    jax.ShapeDtypeStruct((n_hi, B), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xlo_t, xhi_t, ylo_t, yhi_t, neg_t, nhi_t,
       invt_lo, m_lo, betas_l2h, invt_hi, m_hi, betas_h2l, minv)
 
@@ -149,7 +149,7 @@ def mont_mul_kernel_call(xlo_t, xhi_t, ylo_t, yhi_t, neg_t, nhi_t,
 def mont_ladder_kernel_call(r0lo_t, r0hi_t, r1lo_t, r1hi_t, bit_t,
                             neg_t, nhi_t, invt_lo, m_lo, betas_l2h,
                             invt_hi, m_hi, betas_h2l, minv, *,
-                            block_b: int = 256, interpret: bool = True):
+                            block_b: int = 256, interpret: bool | None = None):
     """One fused ladder bit (two Montgomery products + select) per column.
 
     ``bit_t: (1, B)`` int32 exponent bits.  Returns the four updated tiles
@@ -168,6 +168,6 @@ def mont_ladder_kernel_call(r0lo_t, r0hi_t, r1lo_t, r1hi_t, bit_t,
                    jax.ShapeDtypeStruct((n_hi, B), jnp.int32),
                    jax.ShapeDtypeStruct((nch_lo, B), jnp.int32),
                    jax.ShapeDtypeStruct((n_hi, B), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r0lo_t, r0hi_t, r1lo_t, r1hi_t, bit_t, neg_t, nhi_t,
       invt_lo, m_lo, betas_l2h, invt_hi, m_hi, betas_h2l, minv)

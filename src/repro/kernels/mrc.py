@@ -17,7 +17,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import mrc_rows
+from repro.core.dispatch import resolve_interpret
+
+from .common import batch_block, mrc_rows, resident
 
 __all__ = ["mrc_kernel_call"]
 
@@ -30,7 +32,8 @@ def _kernel(x_ref, invt_ref, m_ref, out_ref, *, n: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
-def mrc_kernel_call(x_t, inv_t, m_col, *, block_b: int = 512, interpret: bool = True):
+def mrc_kernel_call(x_t, inv_t, m_col, *, block_b: int = 512,
+                    interpret: bool | None = None):
     """x_t: (n, B) int32 residues (channel-major).  Returns (n, B) digits.
 
     B must be a multiple of block_b (ops.py pads).
@@ -40,12 +43,8 @@ def mrc_kernel_call(x_t, inv_t, m_col, *, block_b: int = 512, interpret: bool = 
     return pl.pallas_call(
         functools.partial(_kernel, n=n),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((n, block_b), lambda b: (0, b)),
-            pl.BlockSpec((n, n), lambda b: (0, 0)),
-            pl.BlockSpec((n, 1), lambda b: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((n, block_b), lambda b: (0, b)),
+        in_specs=[batch_block(n, block_b), resident((n, n)), resident((n, 1))],
+        out_specs=batch_block(n, block_b),
         out_shape=jax.ShapeDtypeStruct((n, B), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x_t, inv_t, m_col)

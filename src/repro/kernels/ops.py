@@ -4,10 +4,10 @@ These present the same (..., n) channel-minor API as repro.core, and handle:
   * layout: transpose to the kernel-native (n, B) channel-major tiles,
   * padding: batch padded to the block size (pad values are benign — every
     kernel is elementwise/per-column in batch),
-  * dispatch: ``interpret=True`` automatically off-TPU so the same call site
-    runs the Mosaic kernel on TPU and the Python interpreter on CPU — the
-    default comes from the ONE resolver in core/dispatch.py
-    (``interpret_default``), shared by every op here,
+  * dispatch: ``interpret=None`` (the default here and on every
+    ``*_kernel_call``) resolves through ``interpret_default`` in
+    core/dispatch.py, so the same call site runs the Mosaic kernel on TPU
+    and the Python interpreter on CPU,
   * constraints: kernels require 15-bit (int32-lane) bases; wider bases fall
     back to the pure-jnp core implementations.
 
@@ -26,7 +26,7 @@ import numpy as np
 
 from repro.core.array import RnsArray
 from repro.core.base import RNSBase
-from repro.core.dispatch import interpret_default as _interpret_default
+from repro.core.dispatch import resolve_interpret
 
 from .modmul import modmul_kernel_call
 from .mont_ladder import mont_ladder_kernel_call, mont_mul_kernel_call
@@ -70,7 +70,6 @@ def mrc_op(base, x=None, *, block_b: int = 512, interpret: bool | None = None):
     """
     if isinstance(base, RnsArray):
         base, x = base.base, base.x
-    interpret = _interpret_default() if interpret is None else interpret
     inv_t, m_col = _tables(base)
     flat, lead = _flatten_batch(x.astype(jnp.int32))
     xt, B = _pad_to(flat.T, block_b, axis=1)
@@ -102,7 +101,6 @@ def modmul_op(base, x=None, y=None, *, block_b: int = 1024,
     else:
         _, m_col = _tables(base)
         nch = base.n
-    interpret = _interpret_default() if interpret is None else interpret
     fx, lead = _flatten_batch(x.astype(jnp.int32))
     fy, _ = _flatten_batch(y.astype(jnp.int32))
     xt, B = _pad_to(fx.T, block_b, axis=1)
@@ -136,7 +134,6 @@ def compare_op(
                             "RnsArray")
         b = a._lift(b)  # validates matching base/layout/mb
         base, x1, xa1, x2, xa2 = a.base, a.x, a.xa, b.x, b.xa
-    interpret = _interpret_default() if interpret is None else interpret
     inv_t, m_col = _tables(base)
     betas_col = jnp.asarray(base.betas_ma_np[:, None], dtype=jnp.int32)
     f1, lead = _flatten_batch(x1.astype(jnp.int32))
@@ -178,7 +175,7 @@ def codec_decode_op(codec, summed, *, block_b: int | None = None,
     base = codec.base
     if base.M >= 1 << 45:
         raise ValueError("codec decode kernel requires M < 2**45 (3 limbs)")
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     inv_t, m_col = _tables(base)
     T = (base.M + 1) // 2
     M = base.M
@@ -225,7 +222,7 @@ def codec_encode_op(codec, g, *, block_b: int | None = None,
     if base.bits > 15:
         raise ValueError("Pallas kernels require bits<=15 (int32 lanes); "
                          "use GradCodec.encode for wider bases")
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     reds = codec.redundant  # (m_a,) or (m_a, m_b)
     m_all = jnp.asarray(
         np.concatenate([base.moduli_np, reds])[:, None], dtype=jnp.int32
@@ -320,7 +317,6 @@ def mont_mul_op(x, y, neg, n_hi, *, block_b: int = 256,
     not constants, broadcast against the batch.  Bitwise-identical to the
     pure-jnp ``_mont_mul_jnp`` reference.
     """
-    interpret = _interpret_default() if interpret is None else interpret
     lo_targets = tuple(int(m) for m in x.lo.channel_moduli)
     tables = [jnp.asarray(t) for t in
               _mont_tables_np(x.lo.base, x.hi.base, lo_targets)]
@@ -340,7 +336,6 @@ def mont_ladder_op(r0, r1, bit, neg, n_hi, *, block_b: int = 256,
                    interpret: bool | None = None):
     """One fused Montgomery-ladder bit: two products + branchless select
     in a single kernel launch.  Returns the updated ``(r0, r1)`` pair."""
-    interpret = _interpret_default() if interpret is None else interpret
     lo_targets = tuple(int(m) for m in r0.lo.channel_moduli)
     tables = [jnp.asarray(t) for t in
               _mont_tables_np(r0.lo.base, r0.hi.base, lo_targets)]

@@ -21,7 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import mrc_rows, to_ma_rows
+from repro.core.dispatch import resolve_interpret
+
+from .common import batch_block, mrc_rows, resident, to_ma_rows
 
 __all__ = ["compare_kernel_call"]
 
@@ -43,7 +45,7 @@ def _kernel(
 @functools.partial(jax.jit, static_argnames=("ma", "block_b", "interpret"))
 def compare_kernel_call(
     x1_t, xa1, x2_t, xa2, inv_t, m_col, betas_col, *, ma: int,
-    block_b: int = 512, interpret: bool = True,
+    block_b: int = 512, interpret: bool | None = None,
 ):
     """x*_t: (n, B) residues; xa*: (1, B) redundant residues.
 
@@ -51,13 +53,13 @@ def compare_kernel_call(
     """
     n, B = x1_t.shape
     grid = (B // block_b,)
-    blk = lambda r: pl.BlockSpec((r, block_b), lambda b: (0, b))
-    tbl = lambda s: pl.BlockSpec(s, lambda b: (0, 0))
+    blk = functools.partial(batch_block, block_b=block_b)
     return pl.pallas_call(
         functools.partial(_kernel, n=n, ma=ma),
         grid=grid,
-        in_specs=[blk(n), blk(1), blk(n), blk(1), tbl((n, n)), tbl((n, 1)), tbl((n, 1))],
+        in_specs=[blk(n), blk(1), blk(n), blk(1), resident((n, n)),
+                  resident((n, 1)), resident((n, 1))],
         out_specs=blk(1),
         out_shape=jax.ShapeDtypeStruct((1, B), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x1_t, xa1, x2_t, xa2, inv_t, m_col, betas_col)

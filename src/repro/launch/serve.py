@@ -26,9 +26,9 @@ shared log).  ``--families llm,crypto`` filters a replay to a subset.
 format (big ints as hex) so a run is replayable.
 
 Smoke flags: ``--smoke`` (the DEFAULT: shrink the arch to the CPU-sized
-config) and ``--no-smoke`` (run the full published config) are an explicit
-pair over one setting — exactly one applies, and the help text of each
-names the default.
+config) and ``--no-smoke`` (run the full published config, weights held in
+the compute dtype ``cfg.dtype``) are an explicit pair over one setting —
+exactly one applies, and the help text of each names the default.
 
 ``--page-size N`` switches the engine onto the paged, prefix-sharing pool
 layout (DESIGN.md §13; ``--pages`` sizes the pool, ``--no-prefix-share``
@@ -70,6 +70,7 @@ iterations in ``offline``/``loadgen``) into the report directory.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import math
 import os
@@ -82,6 +83,7 @@ import jax
 
 import repro  # noqa: F401
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.profiling import ProfilerWindow
 from repro.models import init_params
 from repro.serve.batcher import ContinuousBatcher
@@ -433,6 +435,26 @@ def _offline_main(args, ap, cfg, params, reqs, crypto_ctx, rng,
     return report
 
 
+def serving_config(arch: str, *, smoke: bool):
+    """The config ``main`` serves: the CPU smoke shrink, or the published
+    widths with weights in the compute dtype ``cfg.dtype`` — serving keeps
+    no f32 masters, as the dry-run lowering does (f32 llama3.2-3b weights
+    leave no room for the KV pool on a 16 GiB v5e)."""
+    cfg = get_config(arch)
+    cfg = cfg.smoke() if smoke else dataclasses.replace(
+        cfg, param_dtype=cfg.dtype)
+    cfg.validate()
+    return cfg
+
+
+def serving_params(cfg, seed: int):
+    """Random weights for ``cfg`` from ``seed``, drawn in one compiled
+    program: eagerly, each stacked layer leaf is drawn whole in f32 before
+    its cast to ``cfg.param_dtype``, a transient as large as the bf16
+    weights of the biggest leaves together."""
+    return jax.jit(init_params, static_argnums=0)(cfg, jax.random.key(seed))
+
+
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser(
         description="continuous-batching serve driver (DESIGN.md §12)")
@@ -565,12 +587,10 @@ def main(argv=None) -> dict:
         ap.error("--mode loadgen synthesizes its own Poisson LLM phases; "
                  "drop --trace / --crypto-*")
 
-    cfg = get_config(args.arch)
-    if args.smoke:
-        cfg = cfg.smoke()
-    cfg.validate()
+    enable_compile_cache()
+    cfg = serving_config(args.arch, smoke=args.smoke)
     rng = np.random.default_rng(args.seed)
-    params = init_params(cfg, jax.random.key(args.seed))
+    params = serving_params(cfg, args.seed)
     crypto_ctx = None
     if args.crypto_slots:
         from repro.serve.crypto import CryptoContext

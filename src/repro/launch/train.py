@@ -31,6 +31,7 @@ import numpy as np
 
 import repro  # noqa: F401  (x64)
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.train import checkpointer as ckpt
 from repro.train.data import Prefetcher, SyntheticLM
@@ -53,38 +54,59 @@ def _corrupt_wire(codec):
     return hook
 
 
-def make_rns_dp_step(cfg, opt_cfg, codec, *, repair=False, inject=False):
-    """Data-parallel step with the paper's RNS-exact gradient all-reduce,
-    bucketed: per-device grads encode (fused Pallas kernel when the codec
-    qualifies) into ONE contiguous (n_channels, B_total) int32 buffer, the
-    whole pytree moves in a single per-channel psum, and the fused decode
-    runs at the optimizer boundary inside ``adamw_update``
-    (dist/grad_codec.py, DESIGN.md §9).  Runs under shard_map over the
-    'data' axis.
+def make_dp_step(cfg, opt_cfg, codec=None, *, repair=False, inject=False,
+                 devices=None):
+    """Data-parallel step over a 'data' axis of ``devices`` (default: all,
+    in ``jax.devices()`` order), run under shard_map.
+
+    With ``codec``, the paper's RNS-exact gradient all-reduce, bucketed:
+    per-device grads encode (fused Pallas kernel when the codec qualifies)
+    into ONE contiguous (n_channels, B_total) int32 buffer, the whole
+    pytree moves in a single per-channel psum, and the fused decode runs
+    at the optimizer boundary inside ``adamw_update`` (dist/grad_codec.py,
+    DESIGN.md §9).  Without one, a plain fp32 pmean of the gradients — the
+    baseline the RNS path is compared with.
 
     repair=True adds the RRNS locate-and-correct pass on the wire buffer
     (needs a ``correct=True`` codec, DESIGN.md §10); inject=True corrupts
     one residue first, so the returned step demonstrates in-flight repair.
-    """
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import Mesh, PartitionSpec as P
 
-    ndev = len(jax.devices())
-    mesh = Mesh(np.array(jax.devices()), ("data",))
+    Returns ``(step, mesh)``.  The step donates params and optimizer state
+    (the caller rebinds both to its outputs): at published widths the
+    codec's int32 channel buffers leave no room for a second copy of
+    either on a 16 GiB chip, so place them on ``mesh`` replicated
+    (``replicate``) before the first step.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from repro.dist.sharding import auto_mesh
+
+    devs = list(jax.devices() if devices is None else devices)
+    mesh = auto_mesh((len(devs),), ("data",), devices=devs)
     step = make_train_step(
-        cfg, opt_cfg, rns_codec=codec, rns_axis="data", rns_repair=repair,
+        cfg, opt_cfg, dp_axis="data", rns_codec=codec, rns_repair=repair,
         transport_hook=_corrupt_wire(codec) if inject else None,
     )
-    fn = shard_map(
-        step, mesh,
+    fn = jax.shard_map(
+        step, mesh=mesh,
         in_specs=(P(), P(), P("data")),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
-    return jax.jit(fn), ndev
+    return jax.jit(fn, donate_argnums=(0, 1)), mesh
 
 
-def main(argv=None):
+def replicate(tree, mesh):
+    """Commit ``tree`` to every device of ``mesh`` (what ``make_dp_step``'s
+    step expects of params and optimizer state)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return jax.device_put(tree, NamedSharding(mesh, P()))
+
+
+def main(argv=None) -> dict:
+    """Run the training loop; returns ``{"params", "losses"}`` (the final
+    parameters and the loss of every step this run took)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b")
     ap.add_argument("--smoke", action="store_true", default=True)
@@ -140,6 +162,7 @@ def main(argv=None):
         ap.error("--inject-ckpt-corrupt needs --ckpt-dir (there is no "
                  "checkpoint to corrupt without one)")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
@@ -192,11 +215,13 @@ def main(argv=None):
         codec = GradCodec.make(world=max(len(jax.devices()), 2),
                                fused=not args.unfused_codec,
                                correct=args.rns_correct)
-        step_fn, ndev = make_rns_dp_step(cfg, opt_cfg, codec,
-                                         repair=args.rns_correct)
+        step_fn, mesh = make_dp_step(cfg, opt_cfg, codec,
+                                     repair=args.rns_correct)
+        ndev = mesh.size
+        params, opt_state = replicate((params, opt_state), mesh)
         if args.rns_correct and args.inject_corrupt_step >= 0:
-            inject_fn, _ = make_rns_dp_step(cfg, opt_cfg, codec,
-                                            repair=True, inject=True)
+            inject_fn, _ = make_dp_step(cfg, opt_cfg, codec,
+                                        repair=True, inject=True)
         assert args.batch % ndev == 0, "batch must divide device count"
         reds = "+".join(str(r) for r in codec.redundant)
         print(f"[rns] RNS gradient all-reduce over {ndev} device(s), "
@@ -226,7 +251,7 @@ def main(argv=None):
         args.profile_start_step, args.profile_steps,
         args.profile_dir or args.ckpt_dir or ".", label="train",
     )
-    times = []
+    times, losses = [], []
     try:
         for _ in range(start_step, args.steps):
             window.step()
@@ -241,6 +266,7 @@ def main(argv=None):
             metrics = {k: float(v) for k, v in metrics.items()}
             dt = time.time() - t0
             times.append(dt)
+            losses.append(metrics["loss"])
             med = sorted(times)[len(times) // 2]
             if len(times) > 3 and dt > args.watchdog_x * med:
                 print(f"[watchdog] step {step} took {dt:.2f}s "
@@ -268,7 +294,7 @@ def main(argv=None):
         print(f"[profile] captured {window.captured} step(s) under "
               f"{window.artifact}")
     print("done")
-    return params
+    return {"params": params, "losses": losses}
 
 
 if __name__ == "__main__":

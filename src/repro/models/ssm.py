@@ -113,7 +113,9 @@ def mamba2_forward(params, cfg, u, *, initial_state=None):
     # L[i, j] = exp(cum_i - cum_j) for i >= j else 0.
     rel = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (b, nc, Q, Q, h)
     tri = jnp.tril(jnp.ones((Q, Q), bool))
-    L = jnp.where(tri[None, None, :, :, None], jnp.exp(rel), 0.0)
+    # mask BEFORE the exp: above the diagonal rel > 0 can overflow to inf,
+    # and where(mask, exp(rel), 0) would then backpropagate 0 * inf = NaN
+    L = jnp.exp(jnp.where(tri[None, None, :, :, None], rel, -jnp.inf))
     scores = jnp.einsum("bcqs,bcks->bcqk", Cc, Bc)  # (b, nc, Q, Q)
     y_diag = jnp.einsum(
         "bcqk,bcqkh,bckh,bckhp->bcqhp", scores, L, dtc, xc

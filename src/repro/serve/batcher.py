@@ -274,13 +274,25 @@ class ContinuousBatcher:
             self._solo_zero = _zero_cache(solo_abs)
             self.cache = _zero_cache(pool_abs)
         self.mesh = mesh
+        cache_out = rep_out = None  # jit out_shardings: unpinned
         if mesh is not None:
             self.cache_pspecs = cache_specs(
                 pool_abs, mesh, paged_pool=self.paged
             )
-            self.cache = jax.device_put(
-                self.cache, named_shardings(self.cache_pspecs, mesh)
-            )
+            cache_sh = named_shardings(self.cache_pspecs, mesh)
+            self.cache = jax.device_put(self.cache, cache_sh)
+            # the engine owns its mesh: weights and the other device state
+            # live there too, or a replica's steps would run against the
+            # default device's copies
+            rep_out = named_shardings(jax.sharding.PartitionSpec(), mesh)
+            self.params = jax.device_put(params, rep_out)
+            if self._solo_zero is not None:
+                self._solo_zero = jax.device_put(self._solo_zero, rep_out)
+            # every graph hands the cache back in the layout it was given:
+            # an output spec the partitioner rewrote (P() for P(None, ...))
+            # would key a second trace of the same width mid-run
+            cache_out = cache_sh
+        step_out = None if mesh is None else (rep_out, cache_out)
 
         # The engine's jitted graphs — each traces exactly once per
         # process because every argument keeps a fixed shape across
@@ -299,24 +311,33 @@ class ContinuousBatcher:
                 lambda p, c, t, pos, idx, pg, valid, scr: extend_step(
                     cfg, p, c, t, pos, logit_index=idx,
                     pages=pg, page_size=psz, valid_len=valid, scratch=scr,
-                )
+                ), out_shardings=step_out,
             )
-            self._decode_fn = jax.jit(self._decode_paged_impl)
-            self._copy_fn = jax.jit(self._copy_impl)
+            self._decode_fn = jax.jit(self._decode_paged_impl,
+                                      out_shardings=step_out)
+            self._copy_fn = jax.jit(self._copy_impl, out_shardings=cache_out)
             self._insert_fn = None
         else:
+            # monolithic: extend fills the replicated solo cache, decode
+            # and the splice return the pool
             self._extend_fn = jax.jit(
                 lambda p, c, t, pos, idx: extend_step(
                     cfg, p, c, t, pos, logit_index=idx
-                )
+                ), out_shardings=rep_out,
             )
-            self._decode_fn = jax.jit(self._decode_impl)
-            self._insert_fn = jax.jit(self._insert_impl)
+            self._decode_fn = jax.jit(self._decode_impl,
+                                      out_shardings=step_out)
+            self._insert_fn = jax.jit(self._insert_impl,
+                                      out_shardings=cache_out)
             self._copy_fn = None
         self._fp_fn = (
             jax.jit(self._fp_paged_impl if self.paged else self._fp_impl)
             if rns_verify else None
         )
+        # observation hook: on_first_logits(req, row) sees the device
+        # logits row each request's first token is sampled from (the
+        # logit check against models.train_logits in chip_smoke.py)
+        self.on_first_logits = None
         if rns_verify:
             from repro.dist.fault import WireStore
             from repro.dist.grad_codec import GradCodec
@@ -354,12 +375,23 @@ class ContinuousBatcher:
             self.crypto_state = _zero_cache(
                 crypto_state_abstract(self.crypto_ctx, int(crypto_slots))
             )
+            if mesh is not None:
+                self.crypto_state = jax.device_put(self.crypto_state,
+                                                   rep_out)
             self._crypto_fns = make_crypto_fns(
                 self.crypto_ctx, int(crypto_chunk)
             )
         elif crypto_ctx is not None:
             raise ValueError("crypto_ctx= given but crypto_slots=0; pass "
                              "crypto_slots>=1 to enable the crypto lane")
+
+    def _first_token(self, req: Request, logits) -> int:
+        """Greedy first token from the prefill's last-position logits;
+        ``on_first_logits`` (when set) observes the row before sampling."""
+        row = logits[0, 0]
+        if self.on_first_logits is not None:
+            self.on_first_logits(req, row)
+        return int(jnp.argmax(row))
 
     @property
     def _wire(self) -> dict:
@@ -591,7 +623,7 @@ class ContinuousBatcher:
                     self.params, solo, toks, jnp.int32(ci * C),
                     jnp.int32(idx)
                 )
-        first = int(jnp.argmax(logits[0, 0]))
+        first = self._first_token(req, logits)
         self.cache = self._insert_fn(
             self.cache, solo, jnp.int32(slot.index)
         )
@@ -671,7 +703,7 @@ class ContinuousBatcher:
                     self.params, self.cache, toks, jnp.int32(s0),
                     jnp.int32(idx), pages_row, jnp.int32(C), jnp.int32(0),
                 )
-        first = int(jnp.argmax(logits[0, 0]))
+        first = self._first_token(req, logits)
         # publish fully-covered prompt pages for later admissions to share
         self.sched.register_prompt(slot, prompt)
         if self.rns_verify:

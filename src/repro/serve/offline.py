@@ -38,6 +38,8 @@ import numpy as np
 
 import jax
 
+from repro.dist.sharding import auto_mesh
+
 __all__ = [
     "CompletionPump",
     "OfflineInference",
@@ -211,24 +213,23 @@ class CompletionPump:
 def replica_meshes(n: int, devices=None) -> list:
     """Carve the device fleet into ``n`` per-replica 1-axis meshes.
 
-    Returns ``n`` ``Mesh(("data",))`` objects when the fleet divides
-    evenly with >= 1 device each; otherwise ``n`` Nones (every replica's
-    arrays land on the default device — the single-host CPU case, where
-    replicas still exercise the shared-admission scheduling protocol)."""
+    Returns ``n`` ``Mesh(("data",))`` objects, one per equal share of the
+    fleet.  A one-device host returns ``n`` Nones instead (every replica on
+    that device — the CPU case, where replicas still exercise the
+    shared-admission scheduling protocol); any other fleet that ``n`` does
+    not divide is refused rather than silently stacked on one device."""
     if n < 1:
         raise ValueError("need >= 1 replica")
     devs = list(jax.devices()) if devices is None else list(devices)
-    per = len(devs) // n
-    if n == 1 and per == len(devs) == 1:
-        return [None]  # one replica, one device: no mesh indirection
-    if per < 1 or len(devs) % n:
+    if len(devs) == 1:
         return [None] * n
-    return [
-        jax.sharding.Mesh(
-            np.asarray(devs[i * per:(i + 1) * per]), ("data",)
-        )
-        for i in range(n)
-    ]
+    if len(devs) % n:
+        fits = [d for d in range(1, len(devs) + 1) if len(devs) % d == 0]
+        raise ValueError(f"{n} replicas cannot split {len(devs)} devices "
+                         f"evenly; replica counts that do: {fits}")
+    per = len(devs) // n
+    return [auto_mesh((per,), ("data",), devices=devs[i * per:(i + 1) * per])
+            for i in range(n)]
 
 
 class ReplicaSet:
@@ -594,6 +595,12 @@ class OfflineInference:
             if wall > 0 else 0.0,
             "n_chips": self.n_chips,
             "replicas": len(self.engines),
+            # device ids holding each replica's weights and KV cache
+            "replica_devices": [
+                sorted({d.id for leaf in jax.tree_util.tree_leaves(
+                    (e.params, e.cache)) for d in leaf.devices()})
+                for e in self.engines
+            ],
             "engine_steps": self.replica_set.steps - steps0,
             "dispatched": list(self.replica_set.dispatched),
             "ttft_s": sample_stats(

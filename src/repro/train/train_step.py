@@ -41,7 +41,7 @@ def make_loss_fn(cfg):
 
 def make_train_step(
     cfg, opt_cfg: AdamWConfig, *, microbatches: int = 1, grad_shardings=None,
-    rns_codec=None, rns_axis: str = "data", rns_repair: bool = False,
+    dp_axis: str | None = None, rns_codec=None, rns_repair: bool = False,
     transport_hook=None,
 ):
     """grad_shardings: optional NamedSharding tree matching params.  Pins
@@ -51,13 +51,17 @@ def make_train_step(
     (measured: un-pinned, the partitioner partially shards attention dots by
     head_dim and all-reduces every score block).
 
-    rns_codec: optional ``dist.grad_codec.GradCodec``.  When given, the step
-    must run under shard_map/pmap with a ``rns_axis`` mesh axis: local
-    gradients encode to residue channels, the WHOLE pytree all-reduces in a
-    single bucketed per-channel int32 psum (``tree_pack``), and the fused
-    decode runs inside ``adamw_update`` at the optimizer boundary — the
-    paper's exact, order-independent aggregation on the real hot path
-    (DESIGN.md §9).  Loss metrics are pmean'd over the same axis.
+    dp_axis: the mesh axis the step is data-parallel over (the step then
+    runs under shard_map).  Gradients are averaged across it by a plain
+    fp32 pmean, or through ``rns_codec`` when one is given; loss metrics
+    are pmean'd over it either way.
+
+    rns_codec: optional ``dist.grad_codec.GradCodec`` (needs ``dp_axis``):
+    local gradients encode to residue channels, the WHOLE pytree
+    all-reduces in a single bucketed per-channel int32 psum
+    (``tree_pack``), and the fused decode runs inside ``adamw_update`` at
+    the optimizer boundary — the paper's exact, order-independent
+    aggregation on the real hot path (DESIGN.md §9).
 
     rns_repair: with a locate-and-correct codec (``make(correct=True)``),
     run RRNS repair on the local wire buffer before the psum: any single
@@ -74,6 +78,8 @@ def make_train_step(
             "rns_repair requires a locate-and-correct codec: "
             "GradCodec.make(correct=True)"
         )
+    if rns_codec is not None and dp_axis is None:
+        raise ValueError("rns_codec needs dp_axis (the all-reduce axis)")
     loss_fn = make_loss_fn(cfg)
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -112,6 +118,8 @@ def make_train_step(
             loss, ce, aux = loss * inv, ce * inv, aux * inv
 
         if rns_codec is None:
+            if dp_axis is not None:
+                grads = jax.lax.pmean(grads, dp_axis)
             params, opt_state, gnorm = adamw_update(
                 opt_cfg, params, grads, opt_state
             )
@@ -136,21 +144,22 @@ def make_train_step(
                 # corruption never happened
                 wire, fault = rns_codec.correct_packed(wire)
                 repaired = jax.lax.psum(
-                    jnp.sum(fault >= 0).astype(jnp.int32), rns_axis
+                    jnp.sum(fault >= 0).astype(jnp.int32), dp_axis
                 )
                 unrepairable = jax.lax.psum(
-                    jnp.sum(fault == -2).astype(jnp.int32), rns_axis
+                    jnp.sum(fault == -2).astype(jnp.int32), dp_axis
                 )
-            summed = jax.lax.psum(wire, rns_axis)  # the ONLY grad collective
-            nd = jax.lax.psum(1.0, rns_axis)      # trace-time constant
+            summed = jax.lax.psum(wire, dp_axis)  # the ONLY grad collective
+            nd = jax.lax.psum(1.0, dp_axis)      # trace-time constant
             params, opt_state, gnorm = adamw_update(
                 opt_cfg, params, summed, opt_state,
                 grad_decode=lambda s: tree_decode(
                     rns_codec, s, meta, denom=nd
                 ),
             )
+        if dp_axis is not None:
             loss, ce, aux = (
-                jax.lax.pmean(x, rns_axis) for x in (loss, ce, aux)
+                jax.lax.pmean(x, dp_axis) for x in (loss, ce, aux)
             )
         # the optimizer's post-update step counter rides along so drivers
         # can sanity-check a checkpoint resume against the loop's own step

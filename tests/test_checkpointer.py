@@ -302,6 +302,7 @@ from repro.models import init_params
 from repro.train.optimizer import adamw_init
 from repro.train import checkpointer as cp
 from repro.dist.sharding import named_shardings, opt_state_specs, param_specs
+from repro.dist.sharding import auto_mesh
 
 cfg = get_config("gemma-2b").smoke()
 params = init_params(cfg, jax.random.key(0))
@@ -314,13 +315,13 @@ def shardings(mesh):
     return named_shardings(
         {"params": pspecs, "opt": {"m": z, "v": z, "step": P()}}, mesh)
 
-meshA = jax.make_mesh((4, 2), ("data", "model"))
+meshA = auto_mesh((4, 2), ("data", "model"))
 shA = shardings(meshA)
 tree = jax.device_put({"params": params, "opt": adamw_init(params)}, shA)
 ckpt = tempfile.mkdtemp()
 cp.write_step_dir(ckpt, 7, tree)
 
-meshB = jax.make_mesh((2, 4), ("data", "model"))
+meshB = auto_mesh((2, 4), ("data", "model"))
 shB = shardings(meshB)
 abs_tree = jax.tree_util.tree_map(
     lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)

@@ -13,7 +13,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.dist.fault import repair_packed
@@ -188,16 +187,16 @@ def test_correct_codec_transport_matches_plain_decode(fused):
     mesh = _mesh1()
     rng = np.random.default_rng(6)
     g = jnp.asarray(rng.standard_normal(300).astype(np.float32))
-    out = jax.jit(shard_map(lambda x: rns_psum(codec, x, "data"), mesh,
-                            in_specs=P(), out_specs=P(),
-                            check_rep=False))(g)
+    out = jax.jit(jax.shard_map(lambda x: rns_psum(codec, x, "data"),
+                                mesh=mesh, in_specs=P(), out_specs=P(),
+                                check_vma=False))(g)
     want = codec.decode(codec.fold(codec.encode(g).astype(jnp.int32)))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
     tree = {"a": g, "b": g[:37].reshape(37, 1) * 2.0}
-    got = jax.jit(shard_map(lambda t: rns_psum_tree(codec, t, "data"), mesh,
-                            in_specs=(P(),), out_specs=P(),
-                            check_rep=False))(tree)
+    got = jax.jit(jax.shard_map(lambda t: rns_psum_tree(codec, t, "data"),
+                                mesh=mesh, in_specs=(P(),), out_specs=P(),
+                                check_vma=False))(tree)
     for leaf, ref in zip(jax.tree_util.tree_leaves(got),
                          jax.tree_util.tree_leaves(tree)):
         np.testing.assert_allclose(
@@ -247,12 +246,12 @@ def test_train_step_rns_repair_fixes_injected_corruption():
 
     def run(hook):
         step = make_train_step(cfg, opt_cfg, rns_codec=codec,
-                               rns_axis="data", rns_repair=True,
+                               dp_axis="data", rns_repair=True,
                                transport_hook=hook)
-        fn = jax.jit(shard_map(step, mesh,
-                               in_specs=(P(), P(), P("data")),
-                               out_specs=(P(), P(), P()),
-                               check_rep=False))
+        fn = jax.jit(jax.shard_map(step, mesh=mesh,
+                                   in_specs=(P(), P(), P("data")),
+                                   out_specs=(P(), P(), P()),
+                                   check_vma=False))
         return fn(params, adamw_init(params), batch)
 
     p_clean, _, m_clean = run(None)

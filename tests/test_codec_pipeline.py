@@ -11,7 +11,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.dist.grad_codec import (
@@ -202,13 +201,13 @@ def test_rns_psum_tree_single_collective():
     codec = GradCodec.make(world=4)
     mesh = _mesh1()
     tree = _grad_tree(np.random.default_rng(3))
-    bucketed = jax.make_jaxpr(shard_map(
-        lambda t: rns_psum_tree(codec, t, "data"), mesh,
-        in_specs=(P(),), out_specs=P(), check_rep=False))(tree)
-    per_leaf = jax.make_jaxpr(shard_map(
+    bucketed = jax.make_jaxpr(jax.shard_map(
+        lambda t: rns_psum_tree(codec, t, "data"), mesh=mesh,
+        in_specs=(P(),), out_specs=P(), check_vma=False))(tree)
+    per_leaf = jax.make_jaxpr(jax.shard_map(
         lambda t: jax.tree_util.tree_map(
             lambda g: rns_psum(codec, g, "data"), t),
-        mesh, in_specs=(P(),), out_specs=P(), check_rep=False))(tree)
+        mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False))(tree)
     assert _count_collectives(bucketed.jaxpr) == 1
     assert _count_collectives(per_leaf.jaxpr) == len(
         jax.tree_util.tree_leaves(tree)
@@ -220,13 +219,13 @@ def test_rns_psum_tree_matches_per_leaf_bitwise(fused):
     codec = GradCodec.make(world=4, fused=fused)
     mesh = _mesh1()
     tree = _grad_tree(np.random.default_rng(4))
-    out = jax.jit(shard_map(lambda t: rns_psum_tree(codec, t, "data"), mesh,
-                            in_specs=(P(),), out_specs=P(),
-                            check_rep=False))(tree)
-    ref = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(lambda t: rns_psum_tree(codec, t, "data"),
+                                mesh=mesh, in_specs=(P(),), out_specs=P(),
+                                check_vma=False))(tree)
+    ref = jax.jit(jax.shard_map(
         lambda t: jax.tree_util.tree_map(
             lambda g: rns_psum(codec, g, "data"), t),
-        mesh, in_specs=(P(),), out_specs=P(), check_rep=False))(tree)
+        mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False))(tree)
     assert jax.tree_util.tree_structure(out) == \
         jax.tree_util.tree_structure(tree)
     for a, b in zip(jax.tree_util.tree_leaves(out),
@@ -244,9 +243,9 @@ def test_rns_psum_tree_fused_equals_unfused_bitwise():
     g = _adversarial_grads(fused, rng, n=500)
     tree = {"a": g, "b": g[:37].reshape(37, 1) * 3.0}
     mesh = _mesh1()
-    run = lambda c: jax.jit(shard_map(
-        lambda t: rns_psum_tree(c, t, "data"), mesh,
-        in_specs=(P(),), out_specs=P(), check_rep=False))(tree)
+    run = lambda c: jax.jit(jax.shard_map(
+        lambda t: rns_psum_tree(c, t, "data"), mesh=mesh,
+        in_specs=(P(),), out_specs=P(), check_vma=False))(tree)
     a, b = run(fused), run(plain)
     for x, y in zip(jax.tree_util.tree_leaves(a),
                     jax.tree_util.tree_leaves(b)):
@@ -324,11 +323,11 @@ def test_train_step_rns_codec_smoke():
     for fused in (True, False):
         codec = GradCodec.make(world=2, fused=fused)
         step = make_train_step(cfg, opt_cfg, rns_codec=codec,
-                               rns_axis="data")
-        fn = jax.jit(shard_map(step, mesh,
-                               in_specs=(P(), P(), P("data")),
-                               out_specs=(P(), P(), P()),
-                               check_rep=False))
+                               dp_axis="data")
+        fn = jax.jit(jax.shard_map(step, mesh=mesh,
+                                   in_specs=(P(), P(), P("data")),
+                                   out_specs=(P(), P(), P()),
+                                   check_vma=False))
         p2, _, metrics = fn(params, adamw_init(params), batch)
         assert np.isfinite(float(metrics["loss"]))
         assert np.isfinite(float(metrics["gnorm"]))

@@ -240,19 +240,18 @@ def test_codec_sign_and_magnitude_queries():
 
 
 def test_rns_psum_matches_float_psum():
-    from jax.experimental.shard_map import shard_map
 
     codec = GradCodec.make(world=4)
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     g = jnp.asarray(
         np.random.default_rng(7).standard_normal(48), jnp.float32
     )
-    rns = shard_map(lambda x: rns_psum(codec, x, "data"), mesh,
-                    in_specs=P(), out_specs=P(), check_rep=False)
-    fp = shard_map(
+    rns = jax.shard_map(lambda x: rns_psum(codec, x, "data"), mesh=mesh,
+                        in_specs=P(), out_specs=P(), check_vma=False)
+    fp = jax.shard_map(
         lambda x: jax.lax.psum(x, "data") / jax.lax.psum(
             jnp.ones((), jnp.float32), "data"),
-        mesh, in_specs=P(), out_specs=P(), check_rep=False,
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False,
     )
     np.testing.assert_allclose(
         np.asarray(rns(g)), np.asarray(fp(g)), atol=2.0 ** -codec.frac_bits

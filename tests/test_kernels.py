@@ -177,8 +177,9 @@ def test_codec_encode_kernel_property(data):
     from repro.kernels import codec_encode_op
 
     codec = GradCodec.make(world=data.draw(st.sampled_from([2, 32, 512])))
+    big = float(np.float32(1e30))  # width=32 bounds must be f32-exact
     vals = data.draw(st.lists(
-        st.floats(-1e30, 1e30, width=32), min_size=1, max_size=64,
+        st.floats(-big, big, width=32), min_size=1, max_size=64,
     ))
     g = jnp.asarray(np.asarray(vals, np.float32))
     np.testing.assert_array_equal(

@@ -192,7 +192,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import repro
 from repro.launch.dryrun import lower_cell
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.dist.sharding import auto_mesh
+mesh = auto_mesh((4, 2), ("data", "model"))
 cfg, pa, lowered, meta = lower_cell("whisper-tiny", "train_4k", mesh,
                                     microbatches=4)
 compiled = lowered.compile()
@@ -205,3 +206,62 @@ print("SUBPROC_OK")
         env=env, timeout=420,
     )
     assert "SUBPROC_OK" in out.stdout, out.stderr[-2000:]
+
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+import repro
+from repro.launch.compile_cache import enable_compile_cache
+hits = []
+jax.monitoring.register_event_listener(
+    lambda event, **_: hits.append(event)
+    if event == "/jax/compilation_cache/cache_hits" else None)
+where = enable_compile_cache()
+if {compile}:
+    jax.jit(lambda x: jnp.sin(x) * 3 + x)(jnp.arange(8.0)).block_until_ready()
+print("CACHE", where, len(hits))
+"""
+
+
+def _cache_probe(env, *, compile_fn):
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE.format(compile=compile_fn)],
+        capture_output=True, text=True, env=env, timeout=300,
+    )
+    line = [ln for ln in out.stdout.splitlines() if ln.startswith("CACHE ")]
+    assert line, out.stderr[-2000:]
+    _, where, hits = line[-1].split()
+    return where, int(hits)
+
+
+def test_compile_cache_placement(tmp_path):
+    """``enable_compile_cache``: an outside ``JAX_COMPILATION_CACHE_DIR`` is
+    where entries go, and a second process hits them; without the variable
+    the cache is ``<checkout>/.jax_cache``, the same path in every process."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
+    checkout = os.path.realpath(os.path.join(os.path.dirname(__file__), ".."))
+    default = os.path.join(checkout, ".jax_cache")
+    assert _cache_probe(env, compile_fn=False)[0] == default
+
+    outside = dict(env, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    first = _cache_probe(outside, compile_fn=True)
+    assert first == (str(tmp_path / "cc"), 0)
+    assert os.listdir(tmp_path / "cc")
+    assert _cache_probe(outside, compile_fn=True)[1] >= 1
+
+
+def test_chip_smoke_refuses_without_tpu():
+    """chip_smoke.py never falls back to the CPU: with no TPU it exits
+    non-zero, says so, and prints no result line."""
+    script = os.path.join(os.path.dirname(__file__), "..", "chip_smoke.py")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, script], capture_output=True, text=True, env=env,
+        timeout=300,
+    )
+    assert out.returncode != 0
+    assert "no TPU found" in out.stderr
+    assert '"ok"' not in out.stdout

@@ -187,3 +187,28 @@ def test_windowed_ring_cache_long_decode():
             np.asarray(ref_logits, np.float32),
             rtol=2e-2, atol=2e-2,
         )
+
+
+def test_ssd_gradients_finite_under_strong_decay():
+    """A full chunk of strong decay (the published mamba2 chunk of 128 with
+    |dt*A| near its init maximum) drives exp(cum_i - cum_j) past the f32
+    range above the diagonal.  Those entries are masked out of the
+    forward; their gradients must not turn into NaN (0 * inf)."""
+    import dataclasses
+
+    from repro.train.train_step import make_loss_fn
+
+    cfg = dataclasses.replace(get_config("mamba2-370m").smoke(),
+                              ssm_chunk=128).validate()
+    params = init_params(cfg, jax.random.key(0))
+    layers = params["layers"]
+    layers["mamba"]["A_log"] = jnp.full_like(layers["mamba"]["A_log"],
+                                             np.log(16.0))
+    layers["mamba"]["dt_bias"] = jnp.full_like(layers["mamba"]["dt_bias"],
+                                               2.0)
+    toks = jnp.asarray(np.random.default_rng(0).integers(
+        0, cfg.vocab, size=(1, 129), dtype=np.int32))
+    grads = jax.grad(lambda p: make_loss_fn(cfg)(p, {"tokens": toks})[0])(
+        params)
+    for leaf in jax.tree_util.tree_leaves(grads):
+        assert np.isfinite(np.asarray(leaf)).all()

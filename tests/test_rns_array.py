@@ -248,7 +248,6 @@ def test_pytree_roundtrip_jit_vmap_treemap():
 def test_pytree_psum_single_collective():
     """An RnsArray flows through lax.psum as ONE leaf — the bucketed
     transport's single-collective guarantee survives the typed wire."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     codec = GradCodec.make(world=max(len(jax.devices()), 2))
@@ -260,7 +259,8 @@ def test_pytree_psum_single_collective():
         return jax.lax.psum(arr, "data")
 
     jaxpr = jax.make_jaxpr(
-        shard_map(step, mesh, in_specs=P(), out_specs=P(), check_rep=False)
+        jax.shard_map(step, mesh=mesh, in_specs=P(), out_specs=P(),
+                      check_vma=False)
     )(g)
     assert str(jaxpr).count("psum") == 1
 

@@ -291,6 +291,8 @@ def test_replica_meshes_single_device_fallback():
     assert replica_meshes(3) == [None, None, None]  # 1 device can't split
     with pytest.raises(ValueError):
         replica_meshes(0)
+    with pytest.raises(ValueError, match="evenly"):  # never stacked quietly
+        replica_meshes(3, devices=[object()] * 4)
 
 
 def test_replica_set_shared_queue_least_loaded(cfg, params):

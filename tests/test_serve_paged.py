@@ -39,6 +39,7 @@ from conftest import (
     run_with_row_snapshots,
 )
 from repro.configs import get_config
+from repro.dist.sharding import auto_mesh
 from repro.models import init_params
 from repro.serve.batcher import ContinuousBatcher
 from repro.serve.scheduler import (
@@ -241,7 +242,7 @@ def test_eviction_verify_failure_lands_in_verify_log(cfg, params):
 def test_paged_pool_shards_on_mesh(cfg, params):
     """The pooled buffer takes ``cache_specs(paged_pool=True)``'s layout:
     rank-5 leaves with the page-pool axis carrying the batch sharding."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
     eng = _engine(cfg, params, mesh=mesh)
     spec = eng.cache_pspecs["k"]
     assert len(spec) == 5

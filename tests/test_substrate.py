@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.dist.fault import tensor_fingerprint, verify_fingerprints
 from repro.dist.grad_codec import GradCodec
+from repro.dist.sharding import auto_mesh
 from repro.train import checkpoint as ckpt
 from repro.train.data import Prefetcher, SyntheticLM
 from repro.train.optimizer import AdamWConfig, adamw_init, adamw_update
@@ -65,15 +66,14 @@ def test_codec_sign_and_clip_via_paper_compare(data):
 def test_rns_psum_under_shard_map():
     """End-to-end: rns_psum inside shard_map over a CPU 'data' axis of 1."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.dist.grad_codec import rns_psum
 
     codec = GradCodec.make(world=4)
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     g = jnp.asarray(np.random.default_rng(3).standard_normal(32), jnp.float32)
-    f = shard_map(
-        lambda x: rns_psum(codec, x, "data"), mesh,
-        in_specs=P(), out_specs=P(), check_rep=False,
+    f = jax.shard_map(
+        lambda x: rns_psum(codec, x, "data"), mesh=mesh,
+        in_specs=P(), out_specs=P(), check_vma=False,
     )
     out = f(g)
     np.testing.assert_allclose(np.asarray(out), np.asarray(g),
@@ -189,14 +189,9 @@ def test_param_spec_rules():
     from repro.configs import get_config
     from repro.models import abstract_params
 
-    # axis_types / AxisType only exist on newer jax; the mesh is incidental
-    # here (the assertions below test the rule function directly).
-    kwargs = (
-        {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-        if hasattr(jax.sharding, "AxisType")
-        else {}
-    )
-    mesh = jax.make_mesh((1, 1), ("data", "model"), **kwargs)
+    # the mesh is incidental here (the assertions below test the rule
+    # function directly)
+    mesh = auto_mesh((1, 1), ("data", "model"))
     # fake a 16-wide model axis by monkeypatching shape lookups is overkill;
     # instead test the rule function directly.
     from repro.dist.sharding import _rule

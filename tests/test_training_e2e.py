@@ -39,13 +39,28 @@ def test_model_learns_arith_stream():
 def test_rns_allreduce_training_matches_fp32():
     """The paper-codec gradient path trains to the same losses as plain
     fp32 (quantization at 2^-16 is below optimizer noise)."""
-    from repro.launch.train import make_rns_dp_step
+    from repro.launch.train import make_dp_step
     from repro.dist.grad_codec import GradCodec
 
     cfg = get_config("gemma-2b").smoke()
     opt_cfg = AdamWConfig(lr=1e-3, warmup=5, decay_steps=20, weight_decay=0.0)
     codec = GradCodec.make(world=2)
-    rns_fn, _ = make_rns_dp_step(cfg, opt_cfg, codec)
+    rns_fn, _ = make_dp_step(cfg, opt_cfg, codec)
     l_rns = _run(cfg, 15, step_fn=rns_fn)
     l_fp = _run(cfg, 15)
     np.testing.assert_allclose(l_rns, l_fp, rtol=2e-2, atol=2e-2)
+
+
+def test_fp32_dp_step_matches_plain_step():
+    """``make_dp_step`` without a codec is the fp32-pmean baseline the RNS
+    all-reduce is compared with: over this host's devices it follows the
+    plain jitted step."""
+    from repro.launch.train import make_dp_step
+
+    cfg = get_config("gemma-2b").smoke()
+    # the same schedule _run gives its plain step for 3 steps
+    opt_cfg = AdamWConfig(lr=1e-3, warmup=5, decay_steps=3, weight_decay=0.0)
+    dp_fn, mesh = make_dp_step(cfg, opt_cfg)
+    assert mesh.size == len(jax.devices())
+    np.testing.assert_allclose(_run(cfg, 3, step_fn=dp_fn), _run(cfg, 3),
+                               rtol=1e-5, atol=1e-5)
