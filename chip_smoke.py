@@ -281,10 +281,15 @@ def run_logit_check() -> int:
     eng = ContinuousBatcher(cfg, params, n_slots=4, cache_len=1024,
                             prefill_buckets=pow2_buckets(1024), page_size=16)
     rows = {}
-    eng.on_first_logits = lambda req, row: rows.__setitem__(req.rid, row)
-    for r in reqs:
+    sample = eng._first_token
+    for r in reqs:  # one at a time, so that each first token is r's
+        def keep(logits, rid=r.rid):
+            rows[rid] = logits[0, 0]
+            return sample(logits)
+        eng._first_token = keep
         eng.submit(r)
-    done = {r.rid: r for r in eng.run_to_completion()}
+        eng.run_to_completion()
+    done = {r.rid: r for r in eng.sched.completed}
     require(len(done) == 8, "logit check: requests did not finish")
 
     L = max(len(r.prompt) for r in reqs)

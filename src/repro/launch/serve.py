@@ -65,7 +65,12 @@ engine and raises for them.
 
 ``--profile-start-step/--profile-steps`` capture a JAX profiler trace
 of that window of driver steps (decode ticks in ``sim``, loop
-iterations in ``offline``/``loadgen``) into the report directory.
+iterations in ``offline``/``loadgen``) into the report directory.  The
+trace carries the engine's host spans beside the device's operations:
+``serve.admit``, ``serve.step``, ``serve.write_barrier``, and
+``serve.fp.publish`` / ``serve.fp.verify`` with the number of RRNS
+``codewords`` each handled, so idle time on the chip can be read
+against the engine phase that kept the host busy.
 """
 from __future__ import annotations
 

@@ -50,6 +50,13 @@ buffer via ``verify_packed`` and ``repair_wire`` rebuilds the bad channel
 in place with ``dist.fault.repair_packed`` — fault repair composed with
 serving (DESIGN.md §12).
 
+Profiler spans (``jax.profiler.TraceAnnotation``, free while no trace is
+recording) mark each host phase the chip waits on: ``serve.admit``
+(``try_admit``), ``serve.step`` (the LLM half of ``step``),
+``serve.write_barrier`` (a paged action list), and ``serve.fp.publish`` /
+``serve.fp.verify`` around fingerprint work, each with the number of
+RRNS ``codewords`` it encodes or checks as a stat.
+
 Doctest — admit, stream, retire (a 5-token prompt, 4 greedy tokens)::
 
     >>> import jax
@@ -74,6 +81,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.dist.sharding import cache_specs, named_shardings
 from repro.models import decode_step, extend_step
@@ -300,19 +308,14 @@ class ContinuousBatcher:
         # mode the page table is an int32 ARRAY argument: its contents
         # are data, never trace constants).
         if self.paged:
-            psz = self.page_size
             # valid/scratch are traced int32 DATA (the padded write
             # barrier): the chunk loop passes valid = chunk width (all
             # tokens through the table — chunk-grid pads included, same
             # as ever) with the parking page as a dead scratch operand;
             # bucketed prefill passes valid = real tokens + a live
             # scratch page.  Either way one graph per token width.
-            self._extend_fn = jax.jit(
-                lambda p, c, t, pos, idx, pg, valid, scr: extend_step(
-                    cfg, p, c, t, pos, logit_index=idx,
-                    pages=pg, page_size=psz, valid_len=valid, scratch=scr,
-                ), out_shardings=step_out,
-            )
+            self._extend_fn = jax.jit(self._extend_paged_impl,
+                                      out_shardings=step_out)
             self._decode_fn = jax.jit(self._decode_paged_impl,
                                       out_shardings=step_out)
             self._copy_fn = jax.jit(self._copy_impl, out_shardings=cache_out)
@@ -320,11 +323,8 @@ class ContinuousBatcher:
         else:
             # monolithic: extend fills the replicated solo cache, decode
             # and the splice return the pool
-            self._extend_fn = jax.jit(
-                lambda p, c, t, pos, idx: extend_step(
-                    cfg, p, c, t, pos, logit_index=idx
-                ), out_shardings=rep_out,
-            )
+            self._extend_fn = jax.jit(self._extend_impl,
+                                      out_shardings=rep_out)
             self._decode_fn = jax.jit(self._decode_impl,
                                       out_shardings=step_out)
             self._insert_fn = jax.jit(self._insert_impl,
@@ -334,10 +334,6 @@ class ContinuousBatcher:
             jax.jit(self._fp_paged_impl if self.paged else self._fp_impl)
             if rns_verify else None
         )
-        # observation hook: on_first_logits(req, row) sees the device
-        # logits row each request's first token is sampled from (the
-        # logit check against models.train_logits in chip_smoke.py)
-        self.on_first_logits = None
         if rns_verify:
             from repro.dist.fault import WireStore
             from repro.dist.grad_codec import GradCodec
@@ -385,13 +381,10 @@ class ContinuousBatcher:
             raise ValueError("crypto_ctx= given but crypto_slots=0; pass "
                              "crypto_slots>=1 to enable the crypto lane")
 
-    def _first_token(self, req: Request, logits) -> int:
-        """Greedy first token from the prefill's last-position logits;
-        ``on_first_logits`` (when set) observes the row before sampling."""
-        row = logits[0, 0]
-        if self.on_first_logits is not None:
-            self.on_first_logits(req, row)
-        return int(jnp.argmax(row))
+    def _first_token(self, logits) -> int:
+        """Greedy first token from the prefill's last-position logits (a
+        host sync)."""
+        return int(jnp.argmax(logits[0, 0]))
 
     @property
     def _wire(self) -> dict:
@@ -400,6 +393,12 @@ class ContinuousBatcher:
         return self.wire.raw
 
     # ------------------------------------------------------ jitted graphs
+    def _extend_impl(self, params, cache, tokens, pos, idx):
+        """One prefill chunk (or padded bucket) into the solo cache; the
+        logits of position ``idx`` only."""
+        return extend_step(self.cfg, params, cache, tokens, pos,
+                           logit_index=idx)
+
     def _decode_impl(self, params, cache, tokens, pos):
         """One batched decode step + greedy sampling.  tokens: (B, 1),
         pos: (B,) per-slot write positions."""
@@ -435,6 +434,16 @@ class ContinuousBatcher:
         return jnp.concatenate(sums)
 
     # ---------------------------------------------------- paged-pool graphs
+    def _extend_paged_impl(self, params, cache, tokens, pos, idx, pages,
+                           valid, scratch):
+        """Paged twin of ``_extend_impl``: the first ``valid`` tokens write
+        into the pool through the (1, n_pg) page-table row, the rest into
+        page ``scratch`` (the padded write barrier)."""
+        return extend_step(self.cfg, params, cache, tokens, pos,
+                           logit_index=idx, pages=pages,
+                           page_size=self.page_size, valid_len=valid,
+                           scratch=scratch)
+
     def _decode_paged_impl(self, params, cache, tokens, pos, pages):
         """Paged twin of ``_decode_impl``: the (n_slots, n_pg) page table
         routes each row's read gather and token write (models/attention.py
@@ -487,27 +496,37 @@ class ContinuousBatcher:
         return self.codec.encode_array(fp, channel_major=True)
 
     def _exec_actions(self, actions: list) -> None:
-        """Execute a ``PagedScheduler.plan_write`` action list in order:
-        evictions verify-and-drop the page's fingerprint (its content is
-        still intact at this point), CoW runs the jitted page copy, fresh
-        allocs need no device work.  An eviction-verify MISMATCH is cache
-        corruption caught at the last possible moment — it is recorded in
+        """Execute a ``PagedScheduler.plan_write`` action list: evictions
+        verify-and-drop the page's fingerprint, CoW runs the jitted page
+        copy, fresh allocs need no device work.  The evictions go first:
+        no copy in the list writes a page that the list evicts after it
+        (a copy's target is a page the list already took, evicting it
+        first if need be), so every evicted page is verified with its
+        content intact.  An eviction-verify MISMATCH is cache corruption
+        caught at the last possible moment — it is recorded in
         ``verify_log`` under the page's publisher rid (and in the wire
         stats), not just counted."""
-        for act in actions:
-            if act["op"] == "evict":
-                pid = act["pid"]
-                if self.rns_verify and pid in self.wire:
-                    ok = self.wire.matches(pid, self._page_codeword(pid))
-                    pub = self._page_pub.pop(pid, None)
-                    if not ok:
-                        self.verify_log[pub] = False
-                    self.wire.pop(pid)
-                    self._page_span.pop(pid, None)
-            elif act["op"] == "cow":
-                self.cache = self._copy_fn(
-                    self.cache, jnp.int32(act["src"]), jnp.int32(act["dst"])
-                )
+        if not actions:
+            return
+        with TraceAnnotation("serve.write_barrier"):
+            evicted = [a["pid"] for a in actions if a["op"] == "evict"
+                       and self.rns_verify and a["pid"] in self.wire]
+            if evicted:
+                with TraceAnnotation("serve.fp.verify",
+                                     codewords=len(evicted)):
+                    for pid in evicted:
+                        ok = self.wire.matches(pid, self._page_codeword(pid))
+                        pub = self._page_pub.pop(pid, None)
+                        if not ok:
+                            self.verify_log[pub] = False
+                        self.wire.pop(pid)
+                        self._page_span.pop(pid, None)
+            for act in actions:
+                if act["op"] == "cow":
+                    self.cache = self._copy_fn(
+                        self.cache, jnp.int32(act["src"]),
+                        jnp.int32(act["dst"])
+                    )
 
     # ------------------------------------------------------ admission path
     def _rid_held(self, rid) -> bool:
@@ -566,15 +585,16 @@ class ContinuousBatcher:
         batched cache.  Returns the admitted slots (normally now in
         DECODE; already FREE again if the first token retired the
         request — one-token budget or instant EOS)."""
-        admitted = []
-        while True:
-            slot = self.sched.admit_next(now)
-            if slot is None:
-                break
-            self._prefill_into(slot, now)
-            admitted.append(slot)
-        if self.crypto is not None:
-            self._crypto_admit(now)
+        with TraceAnnotation("serve.admit"):
+            admitted = []
+            while True:
+                slot = self.sched.admit_next(now)
+                if slot is None:
+                    break
+                self._prefill_into(slot, now)
+                admitted.append(slot)
+            if self.crypto is not None:
+                self._crypto_admit(now)
         return admitted
 
     def _prefill_into(self, slot: Slot, now: float) -> None:
@@ -623,17 +643,18 @@ class ContinuousBatcher:
                     self.params, solo, toks, jnp.int32(ci * C),
                     jnp.int32(idx)
                 )
-        first = self._first_token(req, logits)
+        first = self._first_token(logits)
         self.cache = self._insert_fn(
             self.cache, solo, jnp.int32(slot.index)
         )
         if self.rns_verify:
-            fp = self._fp_fn(
-                self.cache, jnp.int32(slot.index), jnp.int32(plen)
-            )
-            self.wire.put(req.rid, self.codec.encode_array(
-                fp, channel_major=True
-            ))
+            with TraceAnnotation("serve.fp.publish", codewords=1):
+                fp = self._fp_fn(
+                    self.cache, jnp.int32(slot.index), jnp.int32(plen)
+                )
+                self.wire.put(req.rid, self.codec.encode_array(
+                    fp, channel_major=True
+                ))
         if self.sched.start_decode(slot, first, now) and self.rns_verify:
             # instant retirement (one-token budget / immediate EOS) never
             # reaches step()'s retirement branch — verify here instead
@@ -703,7 +724,7 @@ class ContinuousBatcher:
                     self.params, self.cache, toks, jnp.int32(s0),
                     jnp.int32(idx), pages_row, jnp.int32(C), jnp.int32(0),
                 )
-        first = self._first_token(req, logits)
+        first = self._first_token(logits)
         # publish fully-covered prompt pages for later admissions to share
         self.sched.register_prompt(slot, prompt)
         if self.rns_verify:
@@ -717,15 +738,20 @@ class ContinuousBatcher:
         publisher's codeword (that sharing is the point: one wire entry
         covers every reader)."""
         ps = self.page_size
+        new = []
         for lp, pid in self.sched.slot_pages(slot.index):
             off = lp * ps
             if off >= plen:
                 break  # decode-region pages are mutable: never fingerprinted
-            if pid in self.wire:
-                continue
-            self._page_span[pid] = min(ps, plen - off)
-            self._page_pub[pid] = slot.req.rid
-            self.wire.put(pid, self._page_codeword(pid))
+            if pid not in self.wire:
+                new.append((pid, min(ps, plen - off)))
+        if not new:
+            return
+        with TraceAnnotation("serve.fp.publish", codewords=len(new)):
+            for pid, span in new:
+                self._page_span[pid] = span
+                self._page_pub[pid] = slot.req.rid
+                self.wire.put(pid, self._page_codeword(pid))
 
     def _retire_paged(self, req: Request) -> None:
         """Paged retirement: verify the request's prompt-page fingerprints
@@ -789,12 +815,13 @@ class ContinuousBatcher:
         )
         self.crypto.bind(slot, req, now)
         if self.rns_verify:
-            fp = self._crypto_fns["fp"](
-                self.crypto_state, jnp.int32(slot.index)
-            )
-            self.wire.put(("crypto", req.rid), self.codec.encode_array(
-                fp, channel_major=True
-            ))
+            with TraceAnnotation("serve.fp.publish", codewords=1):
+                fp = self._crypto_fns["fp"](
+                    self.crypto_state, jnp.int32(slot.index)
+                )
+                self.wire.put(("crypto", req.rid), self.codec.encode_array(
+                    fp, channel_major=True
+                ))
 
     def _crypto_modmul(self, req) -> int:
         ctx, row = self.crypto_ctx, self._crypto_row
@@ -857,42 +884,50 @@ class ContinuousBatcher:
         """One persistent batched decode step over every DECODE slot,
         plus one ``crypto_chunk``-bit ladder advance of the crypto lane
         when it is armed; returns the requests (both families) that
-        retired this step."""
+        retired this step.  The LLM half runs under the ``serve.step``
+        profiler span."""
         crypto_retired = (
             self._crypto_step(now) if self.crypto is not None else []
         )
         decoding = self.sched.decoding_slots()
         if not decoding:
             return crypto_retired
-        if self.paged:
-            # write barrier for this step's one-token writes: page-boundary
-            # crossings allocate, divergence into a shared page CoWs —
-            # all BEFORE the table snapshot rides into the decode graph
-            for slot in decoding:
-                self._exec_actions(
-                    self.sched.plan_write(slot, slot.next_pos, 1)
+        with TraceAnnotation("serve.step"):
+            if self.paged:
+                # write barrier for this step's one-token writes: page-
+                # boundary crossings allocate, divergence into a shared
+                # page CoWs — all BEFORE the table snapshot rides into
+                # the decode graph
+                for slot in decoding:
+                    self._exec_actions(
+                        self.sched.plan_write(slot, slot.next_pos, 1)
+                    )
+            toks, poss = self.sched.step_rows()
+            step_args = [
+                self.params,
+                self.cache,
+                jnp.asarray(toks, jnp.int32)[:, None],
+                jnp.asarray(poss, jnp.int32),
+            ]
+            if self.paged:
+                step_args.append(
+                    jnp.asarray(self.sched.table, jnp.int32)
                 )
-        toks, poss = self.sched.step_rows()
-        step_args = [
-            self.params,
-            self.cache,
-            jnp.asarray(toks, jnp.int32)[:, None],
-            jnp.asarray(poss, jnp.int32),
-        ]
-        if self.paged:
-            step_args.append(jnp.asarray(self.sched.table, jnp.int32))
-        nxt, self.cache = self._decode_fn(*step_args)
-        nxt = np.asarray(nxt)
-        retired = []
-        for slot in decoding:
-            self.sched.advance(slot)
-            req = slot.req
-            if self.sched.record_token(slot, int(nxt[slot.index]), now):
-                retired.append(req)
-                if self.paged:
-                    self._retire_paged(req)
-                elif self.rns_verify:
-                    self.verify_log[req.rid] = self.verify_request(req)
+            nxt, self.cache = self._decode_fn(*step_args)
+            nxt = np.asarray(nxt)
+            retired = []
+            for slot in decoding:
+                self.sched.advance(slot)
+                req = slot.req
+                if self.sched.record_token(slot, int(nxt[slot.index]),
+                                           now):
+                    retired.append(req)
+                    if self.paged:
+                        self._retire_paged(req)
+                    elif self.rns_verify:
+                        self.verify_log[req.rid] = self.verify_request(
+                            req
+                        )
         return retired + crypto_retired
 
     @property
@@ -1186,25 +1221,31 @@ class ContinuousBatcher:
         ``("crypto", rid)`` codeword published at admission."""
         self._require_verify()
         if getattr(req, "family", "llm") == "crypto":
-            fp = self._crypto_fns["fp"](
-                self.crypto_state, jnp.int32(req.slot_index)
-            )
-            fresh = self.codec.encode_array(fp, channel_major=True)
-            return self.wire.matches(("crypto", req.rid), fresh)
+            with TraceAnnotation("serve.fp.verify", codewords=1):
+                fp = self._crypto_fns["fp"](
+                    self.crypto_state, jnp.int32(req.slot_index)
+                )
+                fresh = self.codec.encode_array(fp, channel_major=True)
+                return self.wire.matches(("crypto", req.rid), fresh)
         if self.paged:
-            ok = True
+            pids = []
             for lp, pid in self.sched.slot_pages(req.slot_index):
                 if lp * self.page_size >= len(req.prompt):
                     break  # decode-region pages carry no fingerprints
                 if pid in self.wire:
+                    pids.append(pid)
+            ok = True
+            with TraceAnnotation("serve.fp.verify", codewords=len(pids)):
+                for pid in pids:
                     ok &= self.wire.matches(pid, self._page_codeword(pid))
             return ok
-        fp = self._fp_fn(
-            self.cache, jnp.int32(req.slot_index),
-            jnp.int32(len(req.prompt)),
-        )
-        fresh = self.codec.encode_array(fp, channel_major=True)
-        return self.wire.matches(req.rid, fresh)
+        with TraceAnnotation("serve.fp.verify", codewords=1):
+            fp = self._fp_fn(
+                self.cache, jnp.int32(req.slot_index),
+                jnp.int32(len(req.prompt)),
+            )
+            fresh = self.codec.encode_array(fp, channel_major=True)
+            return self.wire.matches(req.rid, fresh)
 
     def wire_ok(self, key) -> bool:
         """Codeword self-consistency of one stored wire buffer (RRNS

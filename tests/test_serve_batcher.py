@@ -243,19 +243,3 @@ def test_sharded_cache_placement(cfg, params):
     assert len(spec) == 5  # (L, slots, S, g, hd) rule applied
     eng.submit(Request(rid=0, prompt=[1, 2, 3], max_new=3))
     assert len(eng.run_to_completion()[0].out) == 3
-
-
-@pytest.mark.parametrize("paged", [False, True])
-def test_first_logits_hook_sees_the_sampled_row(cfg, params, paged):
-    """on_first_logits observes, for every request, the logits row its
-    first token was sampled from (both prefill paths)."""
-    eng = _engine(cfg, params, paged=paged)
-    rows = {}
-    eng.on_first_logits = lambda req, row: rows.__setitem__(req.rid, row)
-    for rid, prompt in enumerate(([1, 2, 3], [4, 5, 6, 7, 8], [9])):
-        eng.submit(Request(rid=rid, prompt=prompt, max_new=2))
-    done = eng.run_to_completion()
-    assert sorted(rows) == [0, 1, 2]
-    for r in done:
-        assert rows[r.rid].shape == (cfg.vocab,)
-        assert r.out[0] == int(np.argmax(np.asarray(rows[r.rid])))
