@@ -83,11 +83,14 @@ class WireStore:
     """Keyed store of typed RRNS wire fingerprints with detect/repair.
 
     Each entry is a channel-major ``RnsArray`` codeword (the output of
-    ``codec.encode_array(..., channel_major=True)``) under an arbitrary
-    hashable key — the serve engine uses request ids for monolithic slot
-    rows and physical page ids for the paged pool, where ONE stored
-    codeword covers every reader of a shared page: corrupt it and every
-    reader's verify fails; repair it once and every reader re-verifies.
+    ``codec.encode_array(..., channel_major=True)``, or ``host_array`` of
+    residues already read back) under an arbitrary hashable key — the
+    serve engine uses request ids for monolithic slot rows and physical
+    page ids for the paged pool, where ONE stored codeword covers every
+    reader of a shared page: corrupt it and every reader's verify fails;
+    repair it once and every reader re-verifies.  ``matches`` compares on
+    the host, so two host codewords take no device op; ``repair`` and
+    ``corrupt`` leave the entry's residues on the host.
 
     ``stats`` accumulates across the store's lifetime:
       verified / failed           — ``matches`` outcomes (content checks)
@@ -113,6 +116,17 @@ class WireStore:
     def put(self, key, arr) -> None:
         self.raw[key] = arr
 
+    def host_array(self, residues):
+        """A channel-major ``(n_channels, B)`` codeword over HOST (NumPy)
+        residues, typed as ``codec.as_array(..., channel_major=True)``
+        types a device buffer."""
+        from repro.core.array import RnsArray
+
+        c = self.codec
+        return RnsArray(np.ascontiguousarray(residues, np.int32), c.base,
+                        layout=c.layout, signed=True, channel_axis=0,
+                        mb=c.mb)
+
     def get(self, key):
         return self.raw[key]
 
@@ -126,7 +140,8 @@ class WireStore:
         """Bitwise compare a freshly encoded codeword against the stored
         one — the content-integrity check (recomputed fingerprint vs the
         fingerprint taken when the data froze)."""
-        ok = bool(jnp.array_equal(fresh.residues, self.raw[key].residues))
+        ok = bool(np.array_equal(np.asarray(fresh.residues),
+                                 np.asarray(self.raw[key].residues)))
         self.stats["verified" if ok else "failed"] += 1
         return ok
 
@@ -142,7 +157,8 @@ class WireStore:
         """Locate-and-correct the stored codeword in place via
         ``repair_packed``; returns the per-call report dict."""
         fixed, report = repair_packed(self.codec, self.raw[key], wraps=0)
-        self.raw[key] = fixed
+        self.raw[key] = dataclasses.replace(
+            fixed, residues=np.asarray(fixed.residues))
         self.stats["repaired"] += report["repaired"]
         self.stats["unrecoverable"] += report["unrecoverable"]
         return report
@@ -155,10 +171,8 @@ class WireStore:
         arr = self.raw[key]
         mods = tuple(self.codec.base.moduli) + self.codec.redundant
         m = mods[channel]
-        res = arr.residues
-        res = res.at[channel, index].set(
-            (res[channel, index] + jnp.int32(delta)) % m
-        )
+        res = np.array(arr.residues)
+        res[channel, index] = (int(res[channel, index]) + delta) % m
         self.raw[key] = dataclasses.replace(arr, residues=res)
 
 

@@ -48,7 +48,13 @@ means cross-slot clobbering.  The wire buffers themselves are
 locate-and-correct codewords: ``wire_ok`` detects a corrupted stored
 buffer via ``verify_packed`` and ``repair_wire`` rebuilds the bad channel
 in place with ``dist.fault.repair_packed`` — fault repair composed with
-serving (DESIGN.md §12).
+serving (DESIGN.md §12).  On the paged path each publish (a prompt's new
+pages), retirement verify (the request's prompt pages) and eviction
+verify (an action list's evicted pages) is ONE call of the batched
+``_fp_pages_impl`` graph — every page's sums and RRNS encode in one
+fixed-shape program — and ONE readback; the page codewords are sliced
+and compared on the host, and the paged wire store holds them as host
+(NumPy) residues, so a verify runs no per-page device op.
 
 Profiler spans (``jax.profiler.TraceAnnotation``, free while no trace is
 recording) mark each host phase the chip waits on: ``serve.admit``
@@ -331,7 +337,7 @@ class ContinuousBatcher:
                                       out_shardings=cache_out)
             self._copy_fn = None
         self._fp_fn = (
-            jax.jit(self._fp_paged_impl if self.paged else self._fp_impl)
+            jax.jit(self._fp_pages_impl if self.paged else self._fp_impl)
             if rns_verify else None
         )
         if rns_verify:
@@ -470,30 +476,55 @@ class ContinuousBatcher:
 
         return jax.tree_util.tree_map(one, cache)
 
-    def _fp_paged_impl(self, cache, pid, span):
-        """Per-layer masked K/V sums over physical page ``pid``'s prompt
-        span [0, span) -> (2L,) f32 fingerprint vector (paged twin of
-        ``_fp_impl``; one codeword per page, shared by all its readers)."""
-        valid = (jnp.arange(self.page_size) < span).astype(jnp.float32)
-        sums = []
-        for name in ("k", "v"):
-            page = jax.lax.dynamic_index_in_dim(
-                cache[name], pid, axis=1, keepdims=False
-            )  # (L, page, g, hd)
-            sums.append(jnp.sum(
-                page.astype(jnp.float32) * valid[None, :, None, None],
-                axis=(1, 2, 3),
-            ))
-        return jnp.concatenate(sums)
+    def _fp_pages_impl(self, cache, pids, spans):
+        """Per-layer masked K/V sums over each listed physical page's
+        prompt span [0, spans[i]), RRNS-encoded in the same graph ->
+        (n_channels, n_pg * 2L) int32 residues, page i's codeword in
+        columns [2L*i, 2L*(i+1)).  ``pids``/``spans`` are fixed (n_pg,)
+        int32 vectors with the live entries first and span 0 past them,
+        so one graph serves every call; a loop of one turn per live
+        entry reduces one page at a time, never gathering more."""
+        ps, n_pg = self.page_size, pids.shape[0]
+
+        def one(i, sums):
+            valid = (jnp.arange(ps) < spans[i]).astype(jnp.float32)
+            row = []
+            for name in ("k", "v"):
+                page = jax.lax.dynamic_index_in_dim(
+                    cache[name], pids[i], axis=1, keepdims=False
+                )  # (L, page, g, hd)
+                row.append(jnp.sum(
+                    page.astype(jnp.float32) * valid[None, :, None, None],
+                    axis=(1, 2, 3),
+                ))
+            return sums.at[i].set(jnp.concatenate(row))
+
+        width = 2 * cache["k"].shape[0]
+        sums = jax.lax.fori_loop(
+            0, jnp.sum(spans > 0, dtype=jnp.int32), one,
+            jnp.zeros((n_pg, width), jnp.float32),
+        )
+        return self.codec.encode_packed(sums.reshape(-1), channel_major=True)
 
     # ---------------------------------------------------- paged host glue
-    def _page_codeword(self, pid: int):
-        """Freshly recomputed RRNS codeword of page ``pid``'s stored
-        prompt span."""
-        fp = self._fp_fn(
-            self.cache, jnp.int32(pid), jnp.int32(self._page_span[pid])
-        )
-        return self.codec.encode_array(fp, channel_major=True)
+    def _page_codewords(self, pids: list, spans: list | None = None) -> list:
+        """Freshly recomputed RRNS codewords of physical pages ``pids``
+        over their prompt spans (``spans``, else the stored ones): one
+        call of the batched graph and one readback per ``n_pg`` pages,
+        each codeword a host-held channel-major ``RnsArray``."""
+        if spans is None:
+            spans = [self._page_span[pid] for pid in pids]
+        n_pg = self.sched.n_pg
+        width = 2 * self.cache["k"].shape[0]
+        out = []
+        for c0 in range(0, len(pids), n_pg):
+            n = len(pids[c0:c0 + n_pg])
+            ids, span = np.zeros((2, n_pg), np.int32)
+            ids[:n], span[:n] = pids[c0:c0 + n], spans[c0:c0 + n]
+            res = np.asarray(self._fp_fn(self.cache, ids, span))
+            out += [self.wire.host_array(res[:, i * width:(i + 1) * width])
+                    for i in range(n)]
+        return out
 
     def _exec_actions(self, actions: list) -> None:
         """Execute a ``PagedScheduler.plan_write`` action list: evictions
@@ -514,8 +545,9 @@ class ContinuousBatcher:
             if evicted:
                 with TraceAnnotation("serve.fp.verify",
                                      codewords=len(evicted)):
-                    for pid in evicted:
-                        ok = self.wire.matches(pid, self._page_codeword(pid))
+                    fresh = self._page_codewords(evicted)
+                    for pid, cw in zip(evicted, fresh):
+                        ok = self.wire.matches(pid, cw)
                         pub = self._page_pub.pop(pid, None)
                         if not ok:
                             self.verify_log[pub] = False
@@ -747,11 +779,13 @@ class ContinuousBatcher:
                 new.append((pid, min(ps, plen - off)))
         if not new:
             return
+        pids, spans = [pid for pid, _ in new], [span for _, span in new]
         with TraceAnnotation("serve.fp.publish", codewords=len(new)):
-            for pid, span in new:
+            fresh = self._page_codewords(pids, spans)
+            for pid, span, cw in zip(pids, spans, fresh):
                 self._page_span[pid] = span
                 self._page_pub[pid] = slot.req.rid
-                self.wire.put(pid, self._page_codeword(pid))
+                self.wire.put(pid, cw)
 
     def _retire_paged(self, req: Request) -> None:
         """Paged retirement: verify the request's prompt-page fingerprints
@@ -1164,7 +1198,11 @@ class ContinuousBatcher:
         report = {"pages_saved": len(extra["pages"]), "adopted": 0,
                   "repaired_pages": 0, "dropped": 0,
                   "ckpt_repaired_leaves": ck_rep["repaired_leaves"]}
-        for entry in extra["pages"]:
+        # the restored content is fixed from here on: every persisted
+        # page's fresh codeword comes from one batched call up front
+        fresh = self._page_codewords([int(e["pid"]) for e in extra["pages"]],
+                                     [int(e["span"]) for e in extra["pages"]])
+        for entry, cw in zip(extra["pages"], fresh):
             pid, parent = int(entry["pid"]), entry["parent"]
             if parent is not None:
                 parent = int(parent)
@@ -1175,8 +1213,7 @@ class ContinuousBatcher:
             if raw is None:
                 report["dropped"] += 1
                 continue
-            self.wire.put(pid, self.codec.as_array(
-                jnp.asarray(raw, jnp.int32), channel_major=True))
+            self.wire.put(pid, self.wire.host_array(raw))
             self._page_span[pid] = int(entry["span"])
             repaired_here = False
             if not self.wire.ok(pid):
@@ -1187,7 +1224,7 @@ class ContinuousBatcher:
                     self._page_span.pop(pid, None)
                     report["dropped"] += 1
                     continue
-            if not self.wire.matches(pid, self._page_codeword(pid)):
+            if not self.wire.matches(pid, cw):
                 # content/fingerprint disagree: the page is not trustworthy
                 self.wire.pop(pid)
                 self._page_span.pop(pid, None)
@@ -1236,8 +1273,8 @@ class ContinuousBatcher:
                     pids.append(pid)
             ok = True
             with TraceAnnotation("serve.fp.verify", codewords=len(pids)):
-                for pid in pids:
-                    ok &= self.wire.matches(pid, self._page_codeword(pid))
+                for pid, cw in zip(pids, self._page_codewords(pids)):
+                    ok &= self.wire.matches(pid, cw)
             return ok
         with TraceAnnotation("serve.fp.verify", codewords=1):
             fp = self._fp_fn(

@@ -238,6 +238,163 @@ def test_eviction_verify_failure_lands_in_verify_log(cfg, params):
     assert eng.wire.stats["failed"] >= 1
 
 
+@jax.jit
+def _per_page_fp(cache, pid, span):
+    """The per-page fingerprint the batched graph replaced: one page's
+    per-layer masked K/V sums over its prompt span [0, span)."""
+    valid = (jnp.arange(PAGE) < span).astype(jnp.float32)
+    sums = []
+    for name in ("k", "v"):
+        page = jax.lax.dynamic_index_in_dim(cache[name], pid, axis=1,
+                                            keepdims=False)
+        sums.append(jnp.sum(page.astype(jnp.float32)
+                            * valid[None, :, None, None], axis=(1, 2, 3)))
+    return jnp.concatenate(sums)
+
+
+def _oracle_codeword(eng, pid, span):
+    """Single-page oracle: ``_per_page_fp``, then an eager encode."""
+    fp = _per_page_fp(eng.cache, jnp.int32(pid), jnp.int32(span))
+    return np.asarray(eng.codec.encode_array(fp, channel_major=True).residues)
+
+
+def test_batched_codewords_bitwise_equal_the_per_page_path(cfg, params):
+    """Every codeword the batched graph publishes or checks — prompt
+    pages with a partial last page, retirement verifies, a multi-page
+    eviction list — equals the single-page oracle bitwise, and one
+    compiled fingerprint graph serves calls of every page count."""
+    eng = _engine(cfg, params, n_slots=2, n_pages=N_PG + 2,
+                  rns_verify=True, prefill_buckets=(8, 16, 32))
+    calls = []
+    batched, barrier = eng._page_codewords, eng._exec_actions
+    in_barrier = []
+
+    def spy(pids, spans=None):
+        kind = ("evict" if in_barrier
+                else "verify" if pids[0] in eng.wire else "publish")
+        out = batched(pids, spans)
+        if spans is None:
+            spans = [eng._page_span[p] for p in pids]
+        for pid, span, cw in zip(pids, spans, out):
+            np.testing.assert_array_equal(
+                cw.residues, _oracle_codeword(eng, pid, span))
+        calls.append((kind, len(pids)))
+        return out
+
+    def barrier_spy(actions):
+        in_barrier.append(1)
+        try:
+            return barrier(actions)
+        finally:
+            in_barrier.pop()
+
+    eng._page_codewords, eng._exec_actions = spy, barrier_spy
+    rng = np.random.default_rng(5)
+    # 27 tokens: N_PG pages, the last holding 3 prompt positions
+    eng.submit(Request(rid=0, prompt=[int(t) for t in rng.integers(
+        1, cfg.vocab, 27)], max_new=2))
+    eng.try_admit()
+    pages = [pid for _, pid in eng.sched.slot_pages(0)]
+    assert [eng._page_span[p] for p in pages] == [PAGE] * (N_PG - 1) + [3]
+    for pid in pages:
+        np.testing.assert_array_equal(
+            eng.wire.get(pid).residues,
+            _oracle_codeword(eng, pid, eng._page_span[pid]))
+    eng.run_to_completion()
+    # a second distinct 27-token prompt evicts the three retained pages
+    for rid, n in ((1, 27), (2, 5), (3, 20)):
+        eng.submit(Request(rid=rid, prompt=[int(t) for t in rng.integers(
+            1, cfg.vocab, n)], max_new=2))
+        eng.run_to_completion()
+    published = {n for kind, n in calls if kind == "publish"}
+    assert {1, 3, N_PG} <= published
+    assert max(n for kind, n in calls if kind == "evict") >= 2
+    assert any(kind == "verify" for kind, _ in calls)
+    assert all(eng.verify_log.values())
+    assert eng.jit_cache_sizes()["fingerprint"] == 1
+
+
+def test_calls_beyond_n_pg_pages_run_in_chunks(cfg, params):
+    """A call over more than n_pg pages runs the one graph once per n_pg
+    pages; every codeword still equals the single-page oracle."""
+    eng = _engine(cfg, params, n_slots=2, rns_verify=True)
+    eng.submit(Request(rid=0, prompt=list(range(1, 26)), max_new=2))
+    eng.submit(Request(rid=1, prompt=list(range(40, 61)), max_new=2))
+    eng.try_admit()
+    pids = sorted(eng._page_span)
+    assert len(pids) > N_PG
+    fp, n_calls = eng._fp_fn, []
+    eng._fp_fn = lambda *a: (n_calls.append(1), fp(*a))[1]
+    try:
+        fresh = eng._page_codewords(pids * 2)
+    finally:
+        eng._fp_fn = fp
+    assert len(n_calls) == -(-2 * len(pids) // N_PG)
+    for pid, cw in zip(pids * 2, fresh):
+        np.testing.assert_array_equal(
+            cw.residues, _oracle_codeword(eng, pid, eng._page_span[pid]))
+    assert eng.jit_cache_sizes()["fingerprint"] == 1
+
+
+def _corrupt_page(eng, pid):
+    """Bump one K value of physical page ``pid`` in the pool."""
+    k = eng.cache["k"]
+    eng.cache = {**eng.cache, "k": k.at[:, pid, 0].add(
+        jnp.asarray(64, k.dtype))}
+
+
+@pytest.mark.parametrize("where", ["eviction", "retirement"])
+def test_one_corrupt_page_fails_only_its_own_reader(cfg, params, where):
+    """One page of a multi-page check — the middle of an eviction list,
+    or one prompt page of a retiring request — has its K/V bumped: only
+    that page's publisher (eviction) or reader (retirement) reads False,
+    and the wire stats count exactly one failure."""
+    # eviction: 9 usable pages, so the third prompt evicts retained ones;
+    # retirement: full backing, so nothing is evicted
+    eng = _engine(cfg, params, n_slots=2, cache_len=64,
+                  n_pages=10 if where == "eviction" else None,
+                  rns_verify=True, prefill_buckets=(8, 16, 32, 64))
+    rng = np.random.default_rng(9)
+
+    def prompt(n):
+        return [int(t) for t in rng.integers(1, cfg.vocab, n)]
+
+    if where == "eviction":
+        hit = {}
+        barrier = eng._exec_actions
+
+        def corrupt_middle(actions):
+            ev = [a["pid"] for a in actions if a["op"] == "evict"]
+            if len(ev) >= 3 and not hit:
+                hit["pid"] = ev[len(ev) // 2]
+                hit["pub"] = eng._page_pub[hit["pid"]]
+                hit["pubs"] = {eng._page_pub[p] for p in ev}
+                _corrupt_page(eng, hit["pid"])
+            return barrier(actions)
+
+        eng._exec_actions = corrupt_middle
+        for rid, n in ((0, 17), (1, 25), (2, 57)):  # 2 + 3 retained pages
+            eng.submit(Request(rid=rid, prompt=prompt(n), max_new=2))
+            eng.run_to_completion()
+        assert hit, "no eviction list of three or more pages"
+        assert len(hit["pubs"]) > 1  # the list spans two publishers
+        bad, n_reqs = hit["pub"], 3
+    else:
+        eng.submit(Request(rid=0, prompt=prompt(33), max_new=3))
+        eng.submit(Request(rid=1, prompt=prompt(29), max_new=3))
+        assert len(eng.try_admit()) == 2  # both resident, no eviction
+        slot = next(s for s in eng.sched.slots if s.req and s.req.rid == 0)
+        pages = [pid for lp, pid in eng.sched.slot_pages(slot.index)
+                 if lp * PAGE < 33]
+        assert len(pages) >= 3
+        _corrupt_page(eng, pages[1])
+        eng.run_to_completion()
+        bad, n_reqs = 0, 2
+    assert {r for r, ok in eng.verify_log.items() if not ok} == {bad}
+    assert len(eng.verify_log) == n_reqs
+    assert eng.wire.stats["failed"] == 1
+
+
 # ---------------------------------------------------------------- sharding
 def test_paged_pool_shards_on_mesh(cfg, params):
     """The pooled buffer takes ``cache_specs(paged_pool=True)``'s layout:

@@ -56,17 +56,23 @@ def _host_events(logdir):
 
 @pytest.fixture(scope="module")
 def traced(cfg, params, tmp_path_factory):
-    """One traced run: the engine, its host events, the codewords put."""
+    """One traced run: the engine, its host events, the codewords put,
+    and the number of calls of the batched fingerprint graph."""
     eng = _engine(cfg, params)
-    puts = []
-    put = eng.wire.put
+    puts, fp_calls = [], []
+    put, fp = eng.wire.put, eng._fp_fn
     eng.wire.put = lambda key, arr: (puts.append(key), put(key, arr))[1]
+    eng._fp_fn = lambda *a: (fp_calls.append(1), fp(*a))[1]
     logdir = str(tmp_path_factory.mktemp("trace"))
-    with jax.profiler.trace(logdir):
-        for r in _requests():
-            eng.submit(r)
-        done = eng.run_to_completion()
+    try:
+        with jax.profiler.trace(logdir):
+            for r in _requests():
+                eng.submit(r)
+            done = eng.run_to_completion()
+    finally:
+        eng._fp_fn = fp
     return {"eng": eng, "events": _host_events(logdir), "puts": puts,
+            "fp_calls": fp_calls,
             "tokens": {r.rid: list(r.out) for r in done}}
 
 
@@ -102,6 +108,24 @@ def test_codewords_match_the_wire_counts(traced):
     assert eng.wire.stats["failed"] == 0
     assert published == len(traced["puts"]) > 0
     assert all(eng.verify_log.values())
+
+
+def test_one_fingerprint_graph_call_per_paged_span(traced):
+    """On the paged path each ``serve.fp.*`` span makes ONE call of the
+    batched fingerprint graph, whatever its number of codewords."""
+    spans = (_spans(traced, "serve.fp.publish")
+             + _spans(traced, "serve.fp.verify"))
+    assert max(st["codewords"] for *_, st in spans) > 1
+    assert all(st["codewords"] > 0 for *_, st in spans)
+    assert len(traced["fp_calls"]) == len(spans)
+    # a jitted call leaves two nested host events of its name: count the
+    # outer ones
+    graph = {(e[1], e[2]) for e in traced["events"]
+             if e[0] == "PjitFunction(_fp_pages_impl)"}
+    calls = [g for g in graph if not any(
+        h != g and h[0] <= g[0] and g[1] <= h[1] for h in graph)]
+    for _, s, e, _ in spans:
+        assert sum(s <= a and b <= e for a, b in calls) == 1
 
 
 def test_tokens_identical_with_the_profiler_on_and_off(cfg, params, traced):
