@@ -6,6 +6,8 @@ found by name, so a later cell, configuration, traffic mix or per-layer
 metric is added with files and entries alone:
 
 * ``bench/configs/<config>.json``: the configuration as it runs;
+* ``bench/families/<model_type>.py``: ``model_config(spec)``, its
+  published keys translated into the program's ``ModelConfig``;
 * ``bench/traffic/<traffic>.json``: the mix, read by ``gen_traffic``;
 * ``bench/metrics/<metric>.py``, or ``<family>.py`` for a metric named
   ``<family>.<rest>``: a reader ``read(run) -> float | None``;

@@ -15,12 +15,13 @@ window opens, so that the window sees the pool, the slots and the queue
 in their steady state rather than filling from empty; nothing before the
 window is counted.
 
-For the comparison that decides ``correct``, the engine keeps the K/V
+For the comparison that decides ``correct``, the engine keeps the pool
 rows of some requests that retire in the window: the rows each wrote
-into the paged pool, prompt and served tokens alike, read through its
-page-table row at the moment it retires (before the pages are released).
-It keeps the longest request so far, and the requests a draw from the
-seed picks; the reference recomputes those rows after the window.
+into every leaf of the paged pool, prompt and served tokens alike, read
+through its page-table row at the moment it retires (before the pages
+are released), under each leaf's own name in the pool.  It keeps the
+longest request so far, and the requests a draw from the seed picks;
+the reference recomputes those rows after the window.
 """
 from __future__ import annotations
 
@@ -29,6 +30,11 @@ from contextlib import nullcontext
 
 import gen_traffic
 import model_config
+
+
+def _gather_pages(leaves: dict, pages):
+    """The pages ``pages`` of every leaf, in one program."""
+    return {name: x[:, pages] for name, x in leaves.items()}
 
 
 class Engine:
@@ -52,11 +58,10 @@ class Engine:
         self.rs = ReplicaSet([self.eng])
         self.verify_ok = self.verify_failed = 0
         self.warm_sizes = None
-        # K/V rows kept at retirement: rid -> {"k", "v"} (L, rows, g, hd)
+        # pool rows kept at retirement: rid -> {leaf name: (L, rows, ...)}
         self.kv_rows: dict = {}
         self.keep = None                 # keep(req) -> bool, set per window
-        self._gather = jax.jit(lambda cache, pages: (
-            cache["k"][:, pages], cache["v"][:, pages]))
+        self._gather = jax.jit(_gather_pages)
         self._record = self.eng.sched.record_token
         self.eng.sched.record_token = self._record_and_keep
 
@@ -75,18 +80,31 @@ class Engine:
                 self.eng.sched.table[idx], len(req.prompt) + len(req.out) - 1)
         return done
 
+    def paged_leaves(self) -> dict:
+        """Every leaf of the engine's pool with the paged layout
+        ``(L, n_pages, page_size, ...)``, under its own name (its key
+        path in the pool, ``/``-joined)."""
+        import jax
+
+        geo = (self.eng.n_pages, self.eng.page_size)
+        return {jax.tree_util.keystr(path, simple=True, separator="/"): x
+                for path, x in
+                jax.tree_util.tree_leaves_with_path(self.eng.cache)
+                if x.ndim >= 3 and tuple(x.shape[1:3]) == geo}
+
     def rows_of(self, table_row, rows: int) -> dict:
-        """Rows [0, rows) of every layer's K and V, read out of the paged
-        pool through one slot's page-table row."""
+        """Rows [0, rows) of every layer of every paged leaf, read out of
+        the pool through one slot's page-table row: ``{name: (L, rows,
+        ...)}``."""
         import jax.numpy as jnp
         import numpy as np
 
         b = self.page_bucket(rows)
-        k, v = self._gather(self.eng.cache,
-                            jnp.asarray(list(table_row)[:b], jnp.int32))
+        got = self._gather(self.paged_leaves(),
+                           jnp.asarray(list(table_row)[:b], jnp.int32))
         out = {}
-        for name, x in (("k", k), ("v", v)):
-            x = np.asarray(x)            # (L, b, page, g, hd)
+        for name, x in got.items():
+            x = np.asarray(x)            # (L, b, page, ...)
             out[name] = x.reshape(x.shape[0], -1, *x.shape[3:])[:, :rows]
         return out
 
@@ -103,7 +121,7 @@ class Engine:
                                     max_new=2, eos=-1))
             self.eng.run_to_completion()
         self.drain()
-        # the K/V gathers of every page count a kept request can need
+        # the pool gathers of every page count a kept request can need
         row = [0] * self.eng.sched.n_pg
         b = 1
         while True:
@@ -112,11 +130,16 @@ class Engine:
                 break
             b = min(2 * b, self.eng.sched.n_pg)
 
+    def jit_sizes(self) -> dict:
+        """Compiled programs per engine function, and the pool gather's."""
+        return {**self.eng.jit_cache_sizes(),
+                "bench_gather": self._gather._cache_size()}
+
     def snapshot(self) -> None:
-        self.warm_sizes = self.eng.jit_cache_sizes()
+        self.warm_sizes = self.jit_sizes()
 
     def retraces(self) -> int:
-        now = self.eng.jit_cache_sizes()
+        now = self.jit_sizes()
         return sum(now[k] - self.warm_sizes.get(k, 0) for k in now)
 
     def drain(self) -> list:
@@ -177,7 +200,7 @@ def run_window(E: Engine, traffic: dict, seed: int, seconds: float, *,
     """Drive the open loop through the mix's pre-roll and then for
     ``seconds``; with ``trace_dir`` the profiler records the window's last
     ``trace_s`` seconds.  Requests that retire in the window keep their
-    K/V rows (``E.kv_rows``) when they are the longest so far or a draw
+    pool rows (``E.kv_rows``) when they are the longest so far or a draw
     from the seed picks them."""
     import jax
     from repro.serve.scheduler import Request
@@ -281,10 +304,10 @@ def run_window(E: Engine, traffic: dict, seed: int, seconds: float, *,
 
 def sample_finished(W: Window, E: Engine, seed: int, min_tokens: int = 256,
                     max_reqs: int = 8) -> list[dict]:
-    """The requests the reference checks, each with the K/V rows it wrote:
-    the longest request that retired in the window, then the ones the
-    seed's draw kept, in an order drawn from the seed, until
-    ``min_tokens`` served tokens or ``max_reqs`` requests."""
+    """The requests the reference checks, each with the pool rows it wrote
+    under the leaves' names: the longest request that retired in the
+    window, then the ones the seed's draw kept, in an order drawn from the
+    seed, until ``min_tokens`` served tokens or ``max_reqs`` requests."""
     fin = sorted((r for r in W.reqs if r["rid"] in E.kv_rows),
                  key=lambda r: r["rid"])
     if not fin:

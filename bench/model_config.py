@@ -1,48 +1,23 @@
 """A configuration file's published keys, mapped onto the program's
-``ModelConfig``.
+``ModelConfig``, and the engine settings every LLM cell derives from it.
 
 The file under ``bench/configs/`` holds the model as it runs, under the
 keys of its published ``config.json``; its ``engine`` group holds the
-serving settings.  This module is the one place that translates those
-keys, so that a later configuration of the same family needs only a new
-file.
+serving settings.  The keys are translated by the module of the file's
+``model_type``, ``bench/families/<model_type>.py``, found by that name:
+a later configuration, of a family already here or of a new one, comes
+in with new files alone.
 """
 from __future__ import annotations
 
-FAMILIES = {"qwen2_moe": "moe"}
+import harness
 
 
 def model_config(spec: dict):
-    """``repro.models.config.ModelConfig`` for a configuration file."""
-    from repro.models.config import ModelConfig
-
-    eng = spec["engine"]
-    heads = spec["num_attention_heads"]
-    return ModelConfig(
-        name=spec["name"],
-        family=FAMILIES[spec["model_type"]],
-        n_layers=spec["num_hidden_layers"],
-        d_model=spec["hidden_size"],
-        n_heads=heads,
-        n_kv=spec["num_key_value_heads"],
-        head_dim=spec["hidden_size"] // heads,
-        d_ff=spec["moe_intermediate_size"],
-        vocab=spec["vocab_size"],
-        act="swiglu" if spec["hidden_act"] == "silu" else "geglu",
-        rope_theta=float(spec["rope_theta"]),
-        norm_eps=float(spec["rms_norm_eps"]),
-        tie_embeddings=bool(spec["tie_word_embeddings"]),
-        n_experts=spec["num_experts"],
-        top_k=spec["num_experts_per_tok"],
-        n_shared=(spec["shared_expert_intermediate_size"]
-                  // spec["moe_intermediate_size"]),
-        expert_dff=spec["moe_intermediate_size"],
-        capacity_factor=float(eng["capacity_factor"]),
-        dtype=eng["dtype"],
-        param_dtype=eng["param_dtype"],
-        remat=False,
-        zero1=False,
-    ).validate()
+    """``repro.models.config.ModelConfig`` for a configuration file, from
+    the ``model_config(spec)`` of its family's module; with no such module,
+    a ``SetupError`` that names the file to add."""
+    return harness.module("families", spec["model_type"]).model_config(spec)
 
 
 def buckets(eng: dict) -> tuple[int, ...]:
