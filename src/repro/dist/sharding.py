@@ -64,6 +64,8 @@ def _rule(name, shape, model: int, *, parent=None, n_experts: int = 0):
             shard(-2)
         elif name == "wo":
             shard(-3)
+        elif name == "wkv_b":  # MLA latent -> per-head (r, h, nope + v)
+            shard(-2)
         return spec
     if parent == "mlp":
         if name == "wi":
@@ -182,6 +184,8 @@ _CACHE_RULES = {
     "gv": (4, 0, 2),
     "lk": (4, 0, 2),
     "lv": (4, 0, 2),
+    "c_kv": (3, 0, None),  # MLA latent rows (b, S, rank): shared by heads
+    "k_pe": (3, 0, None),  # MLA rope key rows (b, S, rope)
     "ks": (2, 0, 1),    # int8 dequant scales (b, g)
     "vs": (2, 0, 1),
     "enc": (3, 0, None),  # encoder states (b, F, d)

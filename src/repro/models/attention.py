@@ -30,6 +30,7 @@ __all__ = [
     "decode_attention",
     "attn_decode",
     "attn_decode_paged",
+    "page_slots",
 ]
 
 NEG_INF = -1e30
@@ -498,6 +499,26 @@ def attn_decode(
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(dt)), out_cache
 
 
+def page_slots(pages, positions, page_size, valid_len=None, scratch=None):
+    """(physical page, in-page offset), each (b, s), that the new tokens at
+    ``positions`` (b, s) write to through the page table ``pages``; with
+    ``valid_len``/``scratch`` the padded write barrier sends tokens past
+    each row's ``valid_len`` to its ``scratch`` page instead."""
+    s = positions.shape[1]
+    n_pg_tab = pages.shape[1]
+    lp = jnp.clip(positions // page_size, 0, n_pg_tab - 1)  # pads may be OOB
+    pid = jnp.take_along_axis(pages, lp, axis=1)  # (b,s)
+    off = positions % page_size
+    if valid_len is not None:
+        # padded write barrier: pad rows (i >= valid_len) scatter into the
+        # per-row scratch page instead of through the table
+        keep = jnp.arange(s)[None, :] < jnp.reshape(
+            jnp.asarray(valid_len, jnp.int32), (-1, 1))
+        pid = jnp.where(keep, pid, jnp.reshape(
+            jnp.asarray(scratch, jnp.int32), (-1, 1)))
+    return pid, off
+
+
 def attn_decode_paged(
     p, x, k_pool, v_pool, pages, pos, *, page_size, heads, kv, hd, theta,
     window=None, valid_len=None, scratch=None,
@@ -549,18 +570,7 @@ def attn_decode_paged(
     v_new = jnp.einsum("bsd,dgk->bsgk", x, p["wv"].astype(dt))
     q = rope(q, positions, theta)
     k_new = rope(k_new, positions, theta)
-    # scatter each new token to its (physical page, in-page offset)
-    n_pg_tab = pages.shape[1]
-    lp = jnp.clip(positions // page_size, 0, n_pg_tab - 1)  # pads may be OOB
-    pid = jnp.take_along_axis(pages, lp, axis=1)  # (b,s)
-    off = positions % page_size
-    if valid_len is not None:
-        # padded write barrier: pad rows (i >= valid_len) scatter into the
-        # per-row scratch page instead of through the table
-        keep = jnp.arange(s)[None, :] < jnp.reshape(
-            jnp.asarray(valid_len, jnp.int32), (-1, 1))
-        pid = jnp.where(keep, pid, jnp.reshape(
-            jnp.asarray(scratch, jnp.int32), (-1, 1)))
+    pid, off = page_slots(pages, positions, page_size, valid_len, scratch)
     k_pool = k_pool.at[pid, off].set(k_new.astype(k_pool.dtype))
     v_pool = v_pool.at[pid, off].set(v_new.astype(v_pool.dtype))
     n_pg = pages.shape[1]

@@ -1,6 +1,7 @@
 """Model configuration — one dataclass covers all ten assigned families.
 
-Families: dense (GQA/MQA transformer, optional sliding window), moe,
+Families: dense (GQA/MQA transformer, optional sliding window), moe
+(optionally behind leading dense layers, with latent attention),
 ssm (Mamba2/SSD), hybrid (Mamba2 + shared attention), encdec (whisper
 backbone, stub audio frontend), vlm (LM backbone + stub patch embeddings).
 """
@@ -20,12 +21,22 @@ class ModelConfig:
     n_heads: int
     n_kv: int
     head_dim: int
-    d_ff: int
+    d_ff: int                 # dense MLP width (moe: the leading dense layers')
     vocab: int
     act: str = "swiglu"       # swiglu | geglu
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
-    tie_embeddings: bool = True
+    tie_embeddings: bool = True  # False: an untied output head ``head``
+
+    # attention kind: per-head K/V ("mha", grouped when n_kv < n_heads) or
+    # DeepSeek-V2/V3 latent attention ("mla": one normed latent row of
+    # kv_lora_rank plus a shared rope key of qk_rope_dim per token; heads
+    # of qk_nope_dim + qk_rope_dim for q.k and v_head_dim for v)
+    attn: str = "mha"
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
 
     # sliding-window attention (gemma3): every `global_every`-th layer is
     # global, the rest attend within `window`.
@@ -41,6 +52,9 @@ class ModelConfig:
     n_shared: int = 0
     expert_dff: int = 0
     capacity_factor: float = 1.25
+    first_dense: int = 0        # leading dense (MLP of d_ff) layers
+    router: str = "softmax"     # softmax | sigmoid_bias (DeepSeek-V3 noaux_tc)
+    routed_scale: float = 1.0   # routed gates' scale (sigmoid_bias)
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -92,6 +106,16 @@ class ModelConfig:
             assert self.ssm_state > 0 and self.d_inner % self.ssm_headdim == 0
         if self.family == "moe":
             assert self.n_experts > 0 and self.top_k > 0 and self.expert_dff > 0
+            assert self.router in ("softmax", "sigmoid_bias")
+            assert 0 <= self.first_dense < self.n_layers
+        else:
+            assert not self.first_dense, "leading dense layers precede experts"
+        assert self.attn in ("mha", "mla")
+        if self.attn == "mla":
+            assert self.family in ("dense", "moe") and not self.window
+            assert min(self.kv_lora_rank, self.qk_nope_dim, self.v_head_dim) > 0
+            assert self.qk_rope_dim > 0 and self.qk_rope_dim % 2 == 0
+            assert self.v_head_dim <= self.qk_nope_dim + self.qk_rope_dim
         if self.family == "encdec":
             assert self.enc_layers > 0 and self.enc_frames > 0
         if self.family == "vlm":
@@ -117,6 +141,11 @@ class ModelConfig:
             top_k=min(self.top_k, 2) if self.top_k else 0,
             n_shared=min(self.n_shared, 1) if self.n_shared else 0,
             expert_dff=64 if self.expert_dff else 0,
+            first_dense=min(self.first_dense, 1),
+            kv_lora_rank=32 if self.kv_lora_rank else 0,
+            qk_nope_dim=16 if self.qk_nope_dim else 0,
+            qk_rope_dim=8 if self.qk_rope_dim else 0,
+            v_head_dim=16 if self.v_head_dim else 0,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_headdim=16 if self.ssm_state else 64,
             ssm_chunk=16 if self.ssm_state else 128,

@@ -10,13 +10,14 @@ boundary: buf is batch-sharded, expert weights are experts- (moonshot,
 E%16==0) or expert-ff- (qwen, 60e) sharded over 'model', and XLA
 materializes the all-to-all / psum pair exactly there.
 
-(§Perf note: the first implementation sorted GLOBALLY across the sharded
-token axis — measured 717 s of collective time per step on
-moonshot-v1-16b-a3b train_4k, 99% of the step.  Per-row dispatch removes
-it; see EXPERIMENTS.md.)
+Two routers share the dispatch: ``softmax`` (top-k of the softmax,
+renormalised; qwen2-moe) and ``sigmoid_bias`` (DeepSeek-V3 ``noaux_tc``
+with one group: the top-k of sigmoid scores plus a correction bias
+choose the experts, the unbiased scores of those experts, normalised to
+sum 1 and times ``routed_scale``, weight them).
 
-Shared experts (qwen2-moe: 4, moonlight: 2) run densely over all tokens.
-Aux load-balance loss (Switch-style) is returned for the trainer.
+Shared experts (qwen2-moe: 4, moonlight: 2) run densely over all tokens,
+ungated.  Aux load-balance loss (Switch-style) is returned for the trainer.
 """
 from __future__ import annotations
 
@@ -27,7 +28,7 @@ from repro.dist.act_sharding import constrain
 
 from .layers import init_linear
 
-__all__ = ["init_moe", "moe_forward"]
+__all__ = ["init_moe", "route", "moe_forward"]
 
 
 def init_moe(key, cfg, dtype):
@@ -41,7 +42,28 @@ def init_moe(key, cfg, dtype):
     if cfg.n_shared:
         p["shared_wi"] = init_linear(ks[3], (d, 2, cfg.n_shared * f), dtype)
         p["shared_wo"] = init_linear(ks[0], (cfg.n_shared * f, d), dtype)
+    if cfg.router == "sigmoid_bias":
+        # e_score_correction_bias: chooses experts, never weights them
+        p["router_bias"] = init_linear(jax.random.fold_in(key, 4), (E,),
+                                       jnp.float32)
     return p
+
+
+def route(p, cfg, x):
+    """x: (b, s, d) -> (gates (b, s, K) f32, expert ids (b, s, K), the
+    per-expert probabilities (b, s, E) the aux loss balances)."""
+    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), p["router"])
+    if cfg.router == "sigmoid_bias":
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + p["router_bias"], cfg.top_k)
+        gate_vals = jnp.take_along_axis(scores, idx, axis=-1)
+        gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True)
+                                 + 1e-20) * cfg.routed_scale
+        return gate_vals, idx, scores / jnp.sum(scores, -1, keepdims=True)
+    probs = jax.nn.softmax(logits, axis=-1)  # (b, s, E)
+    gate_vals, idx = jax.lax.top_k(probs, cfg.top_k)  # (b, s, K)
+    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    return gate_vals, idx, probs
 
 
 def _dispatch_row(x_row, idx_row, gates_row, E, C, K):
@@ -68,10 +90,7 @@ def moe_forward(p, cfg, x):
     E, K = cfg.n_experts, cfg.top_k
     C = max(1, int(s * K / E * cfg.capacity_factor))  # per-row capacity
 
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)  # (b, s, E)
-    gate_vals, idx = jax.lax.top_k(probs, K)  # (b, s, K)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    gate_vals, idx, probs = route(p, cfg, x)
 
     # Switch-style aux loss (global): E * sum_e fraction_e * mean_prob_e
     onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)  # (b, s, K, E)

@@ -3,8 +3,14 @@ encoder-decoder — init, training forward, prefill, and decode.
 
 Layers are scanned (stacked parameter pytrees) so the HLO stays O(1) in
 depth; heterogeneity (gemma3's 5:1 local:global pattern) rides through the
-scan as a per-layer flag driving a *traced* window value.  Activation
-checkpointing wraps the scan body when cfg.remat.
+scan as a per-layer flag driving a *traced* window value.  Leading dense
+layers before the expert layers (``cfg.first_dense``, DeepSeek-V3's
+``first_k_dense_replace``) are a stack of their own, ``dense_layers``,
+scanned before ``layers``; caches keep one leaf of all layers.  Activation
+checkpointing wraps the scan body when cfg.remat.  The attention of every
+layer is ``cfg.attn``: per-head K/V (``attention.py``) or latent
+(``mla.py``); the head is ``params["embed"]`` when tied, else
+``params["head"]``.
 """
 from __future__ import annotations
 
@@ -19,6 +25,7 @@ from repro.dist.act_sharding import constrain
 from .attention import attn_decode, attn_decode_paged, attn_forward, init_attn
 from .config import ModelConfig
 from .layers import embed, gated_mlp, init_linear, init_mlp, init_norm, rms_norm, unembed
+from .mla import init_mla, mla_decode, mla_decode_paged, mla_forward
 from .moe import init_moe, moe_forward
 
 NO_WINDOW = 1 << 40  # "infinite" traced window for global layers
@@ -49,6 +56,31 @@ def layer_window(cfg, is_global):
     return jnp.where(is_global, jnp.int64(NO_WINDOW), jnp.int64(cfg.window))
 
 
+def _segments(cfg: ModelConfig):
+    """(params key, expert layer?, first layer, end) of each scanned layer
+    stack, in order."""
+    moe, nd, L = cfg.family == "moe", cfg.first_dense, cfg.n_layers
+    if nd:
+        return (("dense_layers", False, 0, nd), ("layers", moe, nd, L))
+    return (("layers", moe, 0, L),)
+
+
+def _head(cfg: ModelConfig, params):
+    return params["embed"] if cfg.tie_embeddings else params["head"]
+
+
+def _cache_names(cfg: ModelConfig) -> tuple[str, ...]:
+    """The per-layer cache leaves of the attention kind."""
+    return ("c_kv", "k_pe") if cfg.attn == "mla" else ("k", "v")
+
+
+def _ffn(cfg, pl, h, moe: bool):
+    """The block's feed-forward: (y, aux loss)."""
+    if moe:
+        return moe_forward(pl["moe"], cfg, h)
+    return gated_mlp(h, pl["mlp"]["wi"], pl["mlp"]["wo"], cfg.act), 0.0
+
+
 def _maybe_remat(f, cfg):
     if not cfg.remat:
         return f
@@ -61,14 +93,16 @@ def _maybe_remat(f, cfg):
 
 
 # --------------------------------------------------------------------- init
-def init_dense_block(key, cfg: ModelConfig, dt):
+def init_dense_block(key, cfg: ModelConfig, dt, moe: bool):
     k1, k2 = jax.random.split(key)
     p = {
         "ln1": init_norm((cfg.d_model,), dt),
-        "attn": init_attn(k1, cfg.d_model, cfg.n_heads, cfg.n_kv, cfg.head_dim, dt),
+        "attn": (init_mla(k1, cfg, dt) if cfg.attn == "mla" else
+                 init_attn(k1, cfg.d_model, cfg.n_heads, cfg.n_kv,
+                           cfg.head_dim, dt)),
         "ln2": init_norm((cfg.d_model,), dt),
     }
-    if cfg.family == "moe":
+    if moe:
         p["moe"] = init_moe(k2, cfg, dt)
     else:
         p["mlp"] = init_mlp(k2, cfg.d_model, cfg.d_ff, dt)
@@ -76,14 +110,20 @@ def init_dense_block(key, cfg: ModelConfig, dt):
 
 
 def init_decoder_only(key, cfg: ModelConfig):
+    """Layer i is drawn from ``split(kL, n_layers)[i]`` whatever its kind;
+    an untied head from ``fold_in(kE, 1)``."""
     dt = _pdtype(cfg)
     kE, kL = jax.random.split(key)
     layer_keys = jax.random.split(kL, cfg.n_layers)
-    return {
-        "embed": init_linear(kE, (cfg.vocab, cfg.d_model), dt, scale=0.02),
-        "layers": jax.vmap(lambda k: init_dense_block(k, cfg, dt))(layer_keys),
-        "final_norm": init_norm((cfg.d_model,), dt),
-    }
+    p = {"embed": init_linear(kE, (cfg.vocab, cfg.d_model), dt, scale=0.02),
+         "final_norm": init_norm((cfg.d_model,), dt)}
+    for name, moe, lo, hi in _segments(cfg):
+        keys = layer_keys if hi - lo == cfg.n_layers else layer_keys[lo:hi]
+        p[name] = jax.vmap(lambda k: init_dense_block(k, cfg, dt, moe))(keys)
+    if not cfg.tie_embeddings:
+        p["head"] = init_linear(jax.random.fold_in(kE, 1),
+                                (cfg.vocab, cfg.d_model), dt)
+    return p
 
 
 def init_encdec(key, cfg: ModelConfig):
@@ -131,45 +171,61 @@ def _attn_kwargs(cfg):
 
 
 def decoder_stack(cfg: ModelConfig, params, x, positions, *, collect_kv=False):
-    """Run the scanned layer stack.  Returns (x, aux_loss, kv_stack|None)."""
-    flags = jnp.asarray(global_flags(cfg))
+    """Run the scanned layer stacks.  Returns (x, aux_loss, cache rows
+    (one stack per ``_cache_names`` leaf) | None)."""
+    flags = global_flags(cfg)
     akw = _attn_kwargs(cfg)
+    impl = "scan" if collect_kv else cfg.attn_impl
 
-    def body(carry, xs):
-        x, aux = carry
-        pl, is_global = xs
-        wv = layer_window(cfg, is_global)
-        if cfg.seq_parallel:
-            # residual stream lives (batch x seq)-sharded between blocks;
-            # the q/k/v and MLP constraints pull full sequences back in
-            # (XLA materializes the AG/RS pair = the usual SP dataflow).
-            x = constrain(x, "batch", "seq", None)
-        h = rms_norm(x, pl["ln1"], cfg.norm_eps)
-        res = attn_forward(
-            pl["attn"], h, positions, window=wv, return_kv=collect_kv,
-            impl="scan" if collect_kv else cfg.attn_impl, **akw,
+    def make_body(moe):
+        def body(carry, xs):
+            x, aux = carry
+            pl, is_global = xs
+            wv = layer_window(cfg, is_global)
+            if cfg.seq_parallel:
+                # residual stream lives (batch x seq)-sharded between blocks;
+                # the q/k/v and MLP constraints pull full sequences back in
+                # (XLA materializes the AG/RS pair = the usual SP dataflow).
+                x = constrain(x, "batch", "seq", None)
+            h = rms_norm(x, pl["ln1"], cfg.norm_eps)
+            if cfg.attn == "mla":
+                res = mla_forward(pl["attn"], cfg, h, positions, impl=impl,
+                                  return_cache=collect_kv)
+            else:
+                res = attn_forward(
+                    pl["attn"], h, positions, window=wv,
+                    return_kv=collect_kv, impl=impl, **akw,
+                )
+            o, kv = res if collect_kv else (res, None)
+            if collect_kv:
+                # pin the collected KV stack so the prefill ys buffer
+                # materializes cache-sharded (heads over model when
+                # divisible, else sequence).  The barrier stops the
+                # constraint propagating INTO the attention loop (which
+                # must see the sequence unsharded).
+                kv = jax.lax.optimization_barrier(kv)
+                heads = ("kv",) if cfg.attn == "mha" else ()
+                kv = tuple(constrain(t, "batch", "?seq", *heads, None)
+                           for t in kv)
+            x = x + o
+            h2 = rms_norm(x, pl["ln2"], cfg.norm_eps)
+            y, a = _ffn(cfg, pl, h2, moe)
+            if moe:
+                aux = aux + a
+            return (x + y, aux), kv
+
+        return body
+
+    carry, kvs = (x, jnp.float32(0.0)), []
+    for name, moe, lo, hi in _segments(cfg):
+        carry, kv = jax.lax.scan(
+            _maybe_remat(make_body(moe), cfg), carry,
+            (params[name], jnp.asarray(flags[lo:hi])),
         )
-        o, kv = res if collect_kv else (res, None)
-        if collect_kv:
-            # pin the collected KV stack so the prefill ys buffer materializes
-            # cache-sharded (heads over model when divisible, else sequence).
-            # The barrier stops the constraint propagating INTO the attention
-            # loop (which must see the sequence unsharded).
-            kv = jax.lax.optimization_barrier(kv)
-            kv = tuple(constrain(t, "batch", "?seq", "kv", None) for t in kv)
-        x = x + o
-        h2 = rms_norm(x, pl["ln2"], cfg.norm_eps)
-        if cfg.family == "moe":
-            y, a = moe_forward(pl["moe"], cfg, h2)
-            aux = aux + a
-        else:
-            y = gated_mlp(h2, pl["mlp"]["wi"], pl["mlp"]["wo"], cfg.act)
-        return (x + y, aux), kv
-
-    (x, aux), kvs = jax.lax.scan(
-        _maybe_remat(body, cfg), (x, jnp.float32(0.0)), (params["layers"], flags)
-    )
-    return x, aux, kvs
+        kvs.append(kv)
+    kvs = kvs[0] if len(kvs) == 1 else jax.tree_util.tree_map(
+        lambda *t: jnp.concatenate(t), *kvs)
+    return carry[0], carry[1], kvs
 
 
 def decoder_only_logits(cfg: ModelConfig, params, batch):
@@ -189,7 +245,7 @@ def decoder_only_logits(cfg: ModelConfig, params, batch):
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if n_prefix:
         x = x[:, n_prefix:]  # logits over text positions only
-    return unembed(x, params["embed"]), aux
+    return unembed(x, _head(cfg, params)), aux
 
 
 def decoder_only_prefill(cfg: ModelConfig, params, batch, cache_len: int):
@@ -209,14 +265,16 @@ def decoder_only_prefill(cfg: ModelConfig, params, batch, cache_len: int):
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     x, _, kvs = decoder_stack(cfg, params, x, positions, collect_kv=True)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = unembed(x[:, -1:], params["embed"])[:, 0]
+    logits = unembed(x[:, -1:], _head(cfg, params))[:, 0]
 
-    k_new, v_new = kvs  # (L, b, s, g, hd)
-    L = cfg.n_layers
-    g, hd = cfg.n_kv, cfg.head_dim
     pad = cache_len - s
     if pad < 0:
         raise ValueError("cache_len < prompt length")
+    if cfg.attn == "mla":  # latent rows (L, b, s, r | rope)
+        cache = {n: jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                 for n, t in zip(_cache_names(cfg), kvs)}
+        return logits, dict(cache, len=jnp.int32(s))
+    k_new, v_new = kvs  # (L, b, s, g, hd)
     if cfg.window and cfg.window_cache:
         return logits, _windowed_cache(cfg, k_new, v_new, s, cache_len)
     cache = {"len": jnp.int32(s)}
@@ -296,7 +354,7 @@ def _windowed_decode(cfg: ModelConfig, params, cache, tokens, pos):
         h2 = rms_norm(x, pl["ln2"], cfg.norm_eps)
         x = x + gated_mlp(h2, pl["mlp"]["wi"], pl["mlp"]["wo"], cfg.act)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = unembed(x[:, 0], params["embed"])
+    logits = unembed(x[:, 0], _head(cfg, params))
     out = {
         "gk": jnp.stack([c["k"] for c in new_g]),
         "gv": jnp.stack([c["v"] for c in new_g]),
@@ -307,79 +365,90 @@ def _windowed_decode(cfg: ModelConfig, params, cache, tokens, pos):
     return logits, out
 
 
-def _linear_cache_stack(cfg: ModelConfig, params, cache, x, pos):
-    """Scanned layer stack over a linear (non-ring) KV cache.
+def _cached_stack(cfg: ModelConfig, params, cache, x, pos, *, pages=None,
+                  page_size=None, valid_len=None, scratch=None):
+    """Scanned layer stacks over a cache: x is (b, s, d) with s >= 1 new
+    tokens starting at position ``pos`` (scalar, or per-row ``(b,)`` for
+    the continuous-batching slot layout).  Shared by the one-token decode
+    step and the chunked prefill-extend path.
 
-    Shared by the one-token decode step and the chunked prefill-extend
-    path: x is (b, s, d) with s >= 1 new tokens starting at position
-    ``pos`` (scalar, or per-row ``(b,)`` for the continuous-batching slot
-    layout).  Returns (x after final norm, k cache stack, v cache stack).
-    """
-    flags = jnp.asarray(global_flags(cfg))
+    Without ``pages`` the cache is linear, (L, b, S, ...) per leaf (int8
+    K/V with their "ks"/"vs" scales too).  With ``pages`` it is the PAGED
+    pool (DESIGN.md §13), (L, P, page_size, ...) per leaf, one pooled
+    buffer of physical pages shared by every slot, and ``pages`` (b, n_pg)
+    maps each row's logical positions to them; ``valid_len``/``scratch``
+    are the padded write barrier.  The paged body mirrors the linear one
+    operation-for-operation (same norms, same residual order, same
+    attention math on the gathered rows), so both layouts produce
+    bitwise-identical activations.  Returns (x after the final norm,
+    {leaf: new (L, ...) stack} for ``_cache_names``)."""
+    names = _cache_names(cfg)
+    paged = pages is not None
+    if paged:
+        assert "ks" not in cache, "paged pools are fp-only"
+    carried = names + (("ks", "vs") if "ks" in cache else ())
+    flags = global_flags(cfg)
     akw = _attn_kwargs(cfg)
 
-    quant = "ks" in cache
+    def attend(pa, h, lc, wv):
+        if cfg.attn == "mla" and paged:
+            o, c, pe = mla_decode_paged(
+                pa, cfg, h, lc["c_kv"], lc["k_pe"], pages, pos,
+                page_size=page_size, valid_len=valid_len, scratch=scratch)
+            return o, {"c_kv": c, "k_pe": pe}
+        if cfg.attn == "mla":
+            return mla_decode(pa, cfg, h, lc, pos)
+        if paged:
+            o, kc, vc = attn_decode_paged(
+                pa, h, lc["k"], lc["v"], pages, pos, page_size=page_size,
+                window=wv, valid_len=valid_len, scratch=scratch, **akw,
+            )
+            return o, {"k": kc, "v": vc}
+        return attn_decode(pa, h, lc, pos, window=wv, **akw)
 
-    def body(x, xs):
-        if quant:
-            pl, is_global, kc, vc, ks, vs = xs
-            layer_cache = {"k": kc, "v": vc, "ks": ks, "vs": vs}
-        else:
-            pl, is_global, kc, vc = xs
-            layer_cache = {"k": kc, "v": vc}
+    def block(pl, moe, x, is_global, lc):
         wv = layer_window(cfg, is_global)
         h = rms_norm(x, pl["ln1"], cfg.norm_eps)
-        o, nc = attn_decode(pl["attn"], h, layer_cache, pos, window=wv, **akw)
+        o, nc = attend(pl["attn"], h, lc, wv)
         x = x + o
         h2 = rms_norm(x, pl["ln2"], cfg.norm_eps)
-        if cfg.family == "moe":
-            y, _ = moe_forward(pl["moe"], cfg, h2)
-        else:
-            y = gated_mlp(h2, pl["mlp"]["wi"], pl["mlp"]["wo"], cfg.act)
-        return x + y, (nc["k"], nc["v"])
+        y, _ = _ffn(cfg, pl, h2, moe)
+        return x + y, {n: nc[n] for n in names}
 
-    xs = (params["layers"], flags, cache["k"], cache["v"])
-    if quant:
-        xs = xs + (cache["ks"], cache["vs"])
-    x, (kc, vc) = jax.lax.scan(body, x, xs)
-    return rms_norm(x, params["final_norm"], cfg.norm_eps), kc, vc
+    segs = _segments(cfg)
+    lc = {n: cache[n] for n in carried}
+    if len(segs) == 1:
+        # one kind of block: its weights and cache leaves ride the scan.
+        # The carried form below would serve here too, but on a TPU v5e it
+        # made Qwen1.5-MoE's paged decode 1.5% and its extend 17% slower.
+        name, moe, _, _ = segs[0]
+        x, new = jax.lax.scan(
+            lambda x, xs: block(xs[0], moe, x, *xs[1:]), x,
+            (params[name], jnp.asarray(flags), lc))
+    else:
+        # leading dense layers, then expert layers: a scan per kind, each
+        # reading and writing its own layers of the whole cache leaves in
+        # its carry (slicing the leaves into per-kind scan inputs and
+        # concatenating the outputs would hold two more pool-sized buffers)
+        def make_body(moe):
+            def body(carry, xs):
+                x, leaves = carry
+                pl, i, is_global = xs
+                x, nc = block(pl, moe, x, is_global, {
+                    n: jax.lax.dynamic_index_in_dim(leaves[n], i, 0, False)
+                    for n in carried})
+                return (x, {n: jax.lax.dynamic_update_index_in_dim(
+                    leaves[n], nc[n], i, 0) if n in nc else leaves[n]
+                    for n in carried}), None
 
+            return body
 
-def _paged_cache_stack(cfg: ModelConfig, params, pool, pages, x, pos,
-                       page_size: int, valid_len=None, scratch=None):
-    """Scanned layer stack over the PAGED KV pool (DESIGN.md §13).
-
-    pool: {"k","v"}: (L, P, page_size, g, hd) — one pooled buffer of
-    physical pages shared by every slot; ``pages``: (b, n_pg) int32 page
-    table mapping each row's logical positions to physical pages.  The
-    body mirrors ``_linear_cache_stack`` operation-for-operation (same
-    norms, same residual order, same attention math on the gathered rows)
-    so paged and monolithic layouts produce bitwise-identical activations.
-    int8-quantized pools are not supported (the serve engine gates them).
-    """
-    assert "ks" not in pool, "paged pools are fp-only"
-    flags = jnp.asarray(global_flags(cfg))
-    akw = _attn_kwargs(cfg)
-
-    def body(x, xs):
-        pl, is_global, kc, vc = xs
-        wv = layer_window(cfg, is_global)
-        h = rms_norm(x, pl["ln1"], cfg.norm_eps)
-        o, kc, vc = attn_decode_paged(
-            pl["attn"], h, kc, vc, pages, pos, page_size=page_size,
-            window=wv, valid_len=valid_len, scratch=scratch, **akw,
-        )
-        x = x + o
-        h2 = rms_norm(x, pl["ln2"], cfg.norm_eps)
-        if cfg.family == "moe":
-            y, _ = moe_forward(pl["moe"], cfg, h2)
-        else:
-            y = gated_mlp(h2, pl["mlp"]["wi"], pl["mlp"]["wo"], cfg.act)
-        return x + y, (kc, vc)
-
-    xs = (params["layers"], flags, pool["k"], pool["v"])
-    x, (kc, vc) = jax.lax.scan(body, x, xs)
-    return rms_norm(x, params["final_norm"], cfg.norm_eps), kc, vc
+        for name, moe, lo, hi in segs:
+            (x, lc), _ = jax.lax.scan(make_body(moe), (x, lc), (
+                params[name], jnp.arange(lo, hi, dtype=jnp.int32),
+                jnp.asarray(flags[lo:hi])))
+        new = {n: lc[n] for n in names}
+    return rms_norm(x, params["final_norm"], cfg.norm_eps), new
 
 
 def decoder_only_decode(cfg: ModelConfig, params, cache, tokens, pos,
@@ -388,22 +457,14 @@ def decoder_only_decode(cfg: ModelConfig, params, cache, tokens, pos,
     token, or ``(b,)`` per-row positions (continuous-batching slots).
     With ``pages``/``page_size`` the cache is a paged pool and reads/
     writes route through the page-table indirection (DESIGN.md §13)."""
-    if pages is not None:
-        dt = _dtype(cfg)
-        x = embed(tokens, params["embed"], dt)
-        x, kc, vc = _paged_cache_stack(cfg, params, cache, pages, x, pos,
-                                       page_size)
-        logits = unembed(x[:, 0], params["embed"])
-        out = dict(cache, k=kc, v=vc)
-        out["len"] = cache["len"] + 1
-        return logits, out
     if "lk" in cache:
         return _windowed_decode(cfg, params, cache, tokens, pos)
     dt = _dtype(cfg)
     x = embed(tokens, params["embed"], dt)
-    x, kc, vc = _linear_cache_stack(cfg, params, cache, x, pos)
-    logits = unembed(x[:, 0], params["embed"])
-    out = dict(cache, k=kc, v=vc)
+    x, new = _cached_stack(cfg, params, cache, x, pos, pages=pages,
+                           page_size=page_size)
+    logits = unembed(x[:, 0], _head(cfg, params))
+    out = dict(cache, **new)
     out["len"] = cache["len"] + 1
     return logits, out
 
@@ -434,21 +495,19 @@ def decoder_only_extend(cfg: ModelConfig, params, cache, tokens, pos,
             "extend over grouped ring caches is unsupported; build the "
             "cache with window_cache=False (full-length + window mask)"
         )
-    dt = _dtype(cfg)
-    x = embed(tokens, params["embed"], dt)
-    if pages is not None:
-        x, kc, vc = _paged_cache_stack(cfg, params, cache, pages, x, pos,
-                                       page_size, valid_len=valid_len,
-                                       scratch=scratch)
-    else:
+    if pages is None:
         assert valid_len is None and scratch is None, \
             "the padded write barrier is a paged-pool construct"
-        x, kc, vc = _linear_cache_stack(cfg, params, cache, x, pos)
+    dt = _dtype(cfg)
+    x = embed(tokens, params["embed"], dt)
+    x, new = _cached_stack(cfg, params, cache, x, pos, pages=pages,
+                           page_size=page_size, valid_len=valid_len,
+                           scratch=scratch)
     if logit_index is not None:
         x = jax.lax.dynamic_index_in_dim(x, logit_index, axis=1,
                                          keepdims=True)
-    logits = unembed(x, params["embed"])
-    out = dict(cache, k=kc, v=vc)
+    logits = unembed(x, _head(cfg, params))
+    out = dict(cache, **new)
     out["len"] = cache["len"] + tokens.shape[1]
     return logits, out
 

@@ -99,6 +99,21 @@ __all__ = ["ContinuousBatcher"]
 _SUPPORTED = ("dense", "moe")
 
 
+def _fp_leaves(cache) -> list[str]:
+    """The cache leaves a fingerprint reduces, in a fixed order: every
+    per-layer stack (``k``/``v``, or MLA's ``c_kv``/``k_pe``), not the
+    ``len`` scalar."""
+    return sorted(n for n, x in cache.items() if getattr(x, "ndim", 0) >= 3)
+
+
+def _masked_layer_sums(x, valid):
+    """(L,) f32 sums of ``x`` (L, S, ...) over positions where ``valid``
+    (S,) is 1, and every trailing axis."""
+    tail = (None,) * (x.ndim - 2)
+    return jnp.sum(x.astype(jnp.float32) * valid[(None, slice(None)) + tail],
+                   axis=tuple(range(1, x.ndim)))
+
+
 def _zero_cache(abs_tree):
     """Concrete all-zero cache matching an abstract decode-cache pytree
     ("len" becomes the int32 scalar 0)."""
@@ -425,18 +440,18 @@ class ContinuousBatcher:
         return jax.tree_util.tree_map(one, batch_cache, solo_cache)
 
     def _fp_impl(self, cache, slot, plen):
-        """Per-layer masked K/V sums over slot row ``slot``'s immutable
-        prompt region [0, plen) -> (2L,) f32 fingerprint vector."""
-        valid = (jnp.arange(cache["k"].shape[2]) < plen).astype(jnp.float32)
+        """Per-layer masked sums of every cache leaf over slot row
+        ``slot``'s immutable prompt region [0, plen) -> (n_leaves * L,)
+        f32 fingerprint vector."""
+        names = _fp_leaves(cache)
+        valid = (jnp.arange(cache[names[0]].shape[2]) < plen).astype(
+            jnp.float32)
         sums = []
-        for name in ("k", "v"):
+        for name in names:
             row = jax.lax.dynamic_index_in_dim(
                 cache[name], slot, axis=1, keepdims=False
-            )  # (L, S, g, hd)
-            sums.append(jnp.sum(
-                row.astype(jnp.float32) * valid[None, :, None, None],
-                axis=(1, 2, 3),
-            ))
+            )  # (L, S, ...)
+            sums.append(_masked_layer_sums(row, valid))
         return jnp.concatenate(sums)
 
     # ---------------------------------------------------- paged-pool graphs
@@ -477,29 +492,28 @@ class ContinuousBatcher:
         return jax.tree_util.tree_map(one, cache)
 
     def _fp_pages_impl(self, cache, pids, spans):
-        """Per-layer masked K/V sums over each listed physical page's
-        prompt span [0, spans[i]), RRNS-encoded in the same graph ->
-        (n_channels, n_pg * 2L) int32 residues, page i's codeword in
-        columns [2L*i, 2L*(i+1)).  ``pids``/``spans`` are fixed (n_pg,)
+        """Per-layer masked sums of every pool leaf (``_fp_leaves``) over
+        each listed physical page's prompt span [0, spans[i]),
+        RRNS-encoded in the same graph -> (n_channels, n_pg * W) int32
+        residues, W = n_leaves * L, page i's codeword in columns
+        [W*i, W*(i+1)).  ``pids``/``spans`` are fixed (n_pg,)
         int32 vectors with the live entries first and span 0 past them,
         so one graph serves every call; a loop of one turn per live
         entry reduces one page at a time, never gathering more."""
         ps, n_pg = self.page_size, pids.shape[0]
+        names = _fp_leaves(cache)
 
         def one(i, sums):
             valid = (jnp.arange(ps) < spans[i]).astype(jnp.float32)
             row = []
-            for name in ("k", "v"):
+            for name in names:
                 page = jax.lax.dynamic_index_in_dim(
                     cache[name], pids[i], axis=1, keepdims=False
-                )  # (L, page, g, hd)
-                row.append(jnp.sum(
-                    page.astype(jnp.float32) * valid[None, :, None, None],
-                    axis=(1, 2, 3),
-                ))
+                )  # (L, page, ...)
+                row.append(_masked_layer_sums(page, valid))
             return sums.at[i].set(jnp.concatenate(row))
 
-        width = 2 * cache["k"].shape[0]
+        width = self._fp_width()
         sums = jax.lax.fori_loop(
             0, jnp.sum(spans > 0, dtype=jnp.int32), one,
             jnp.zeros((n_pg, width), jnp.float32),
@@ -507,6 +521,11 @@ class ContinuousBatcher:
         return self.codec.encode_packed(sums.reshape(-1), channel_major=True)
 
     # ---------------------------------------------------- paged host glue
+    def _fp_width(self) -> int:
+        """Codeword width of one page: a sum per layer of each leaf."""
+        names = _fp_leaves(self.cache)
+        return len(names) * self.cache[names[0]].shape[0]
+
     def _page_codewords(self, pids: list, spans: list | None = None) -> list:
         """Freshly recomputed RRNS codewords of physical pages ``pids``
         over their prompt spans (``spans``, else the stored ones): one
@@ -515,7 +534,7 @@ class ContinuousBatcher:
         if spans is None:
             spans = [self._page_span[pid] for pid in pids]
         n_pg = self.sched.n_pg
-        width = 2 * self.cache["k"].shape[0]
+        width = self._fp_width()
         out = []
         for c0 in range(0, len(pids), n_pg):
             n = len(pids[c0:c0 + n_pg])
