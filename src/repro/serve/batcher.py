@@ -91,6 +91,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.dist.sharding import cache_specs, named_shardings
 from repro.models import decode_step, extend_step
+from repro.models.moe import dispatch_form
 from repro.serve.scheduler import PagedScheduler, Request, Slot, SlotScheduler
 from repro.serve.serve_step import cache_abstract, paged_pool_abstract
 
@@ -219,6 +220,9 @@ class ContinuousBatcher:
         self.rns_verify = bool(rns_verify)
         self.paged = page_size is not None
         self.page_size = int(page_size) if self.paged else None
+        # extend and decode launches of a MoE model by the expert dispatch
+        # form their token width selects (``models.moe.dispatch_form``)
+        self.moe_launches = {"grouped": 0, "buffer": 0}
 
         self.prefill_buckets: tuple[int, ...] | None = None
         if prefill_buckets is not None:
@@ -664,6 +668,7 @@ class ContinuousBatcher:
             toks = jnp.asarray(
                 [prompt + [0] * (bucket - plen)], jnp.int32
             )
+            self._count_moe(bucket)
             logits, solo = self._extend_fn(
                 self.params, solo, toks, jnp.int32(0), jnp.int32(plen - 1)
             )
@@ -690,6 +695,7 @@ class ContinuousBatcher:
                 # below it); the traced index keeps the unembed to one
                 # row per call
                 idx = last if ci == n_chunks - 1 else 0
+                self._count_moe(C)
                 logits, solo = self._extend_fn(
                     self.params, solo, toks, jnp.int32(ci * C),
                     jnp.int32(idx)
@@ -743,6 +749,7 @@ class ContinuousBatcher:
             toks = jnp.asarray(
                 [prompt[start:] + [0] * (bucket - need)], jnp.int32
             )
+            self._count_moe(bucket)
             logits, self.cache = self._extend_fn(
                 self.params, self.cache, toks, jnp.int32(start),
                 jnp.int32(need - 1), pages_row, jnp.int32(need),
@@ -771,6 +778,7 @@ class ContinuousBatcher:
                 # chunk-grid pads keep writing THROUGH the table (their
                 # pages are reserved for this slot's decode span anyway):
                 # valid = full width, parking page as dead scratch operand
+                self._count_moe(C)
                 logits, self.cache = self._extend_fn(
                     self.params, self.cache, toks, jnp.int32(s0),
                     jnp.int32(idx), pages_row, jnp.int32(C), jnp.int32(0),
@@ -966,6 +974,7 @@ class ContinuousBatcher:
                 step_args.append(
                     jnp.asarray(self.sched.table, jnp.int32)
                 )
+            self._count_moe(1)
             nxt, self.cache = self._decode_fn(*step_args)
             nxt = np.asarray(nxt)
             retired = []
@@ -1065,6 +1074,16 @@ class ContinuousBatcher:
             if b >= plen:
                 return b
         return None
+
+    def _count_moe(self, width: int) -> None:
+        if self.cfg.family == "moe":
+            self.moe_launches[dispatch_form(self.cfg, width)] += 1
+
+    def moe_stats(self) -> dict:
+        """Extend and decode launches of a MoE model by expert dispatch
+        form, ``{"grouped": n, "buffer": n}``: how often the grouped form
+        (models/moe.py) runs in place of the capacity buffer."""
+        return dict(self.moe_launches)
 
     def bucket_stats(self) -> dict:
         """Bucketed-prefill accounting: hits per width, chunk-loop

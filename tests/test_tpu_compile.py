@@ -151,3 +151,32 @@ def test_full_width_step_fits_one_v5e(one_chip, which):
     # the weights alone: bf16 llama3.2-3b is ~6.4 GB (f32 would not fit)
     assert mem.argument_size_in_bytes > 6 << 30
     assert not re.search(r"= f64\[", compiled.as_text())
+
+
+@pytest.mark.parametrize("preset,E,K,cf", (
+    ("qwen2-moe-a2.7b", 60, 4, 15.0), ("moonshot-v1-16b-a3b", 64, 6, 11.0)))
+def test_grouped_expert_layer_compiles_for_v5e(one_chip, preset, E, K, cf):
+    """One expert layer at published widths and the widest bucket (4096
+    tokens) at the benchmark's dropless capacity runs the grouped form:
+    XLA's ragged-dot lowers to a Mosaic kernel, and the program holds
+    less than one (E, C, d) expert buffer of the buffer form."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models.moe import capacity, dispatch_form, init_moe, moe_forward
+
+    cfg = dataclasses.replace(get_config(preset), n_experts=E, top_k=K,
+                              capacity_factor=cf)
+    s, d = 4096, cfg.d_model
+    assert dispatch_form(cfg, s) == "grouped"
+    p = jax.eval_shape(lambda: init_moe(jax.random.key(0), cfg, jnp.bfloat16))
+    p = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip), p)
+    x = jax.ShapeDtypeStruct((1, s, d), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda p, x: moe_forward(p, cfg, x)).lower(
+        p, x).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged-dot" in text
+    assert not re.search(r"= f64\[", text)
+    buffer_bytes = E * capacity(cfg, s) * d * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < buffer_bytes
