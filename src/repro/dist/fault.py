@@ -15,9 +15,9 @@ located and CORRECTED in place (DESIGN.md §10) and the step keeps going.
 
 ``WireStore`` packages the detect/locate-and-correct plumbing as a keyed
 store of typed ``RnsArray`` wire fingerprints — the serve engine keys it by
-request id (monolithic slot rows, DESIGN.md §12) or by physical cache page
-(the paged pool, DESIGN.md §13), where one stored codeword serves every
-reader of a shared page.
+physical cache page (the paged pool, DESIGN.md §13), where one stored
+codeword serves every reader of a shared page, and by ``("crypto", rid)``
+for crypto lane slots (DESIGN.md §15).
 """
 from __future__ import annotations
 
@@ -85,9 +85,8 @@ class WireStore:
     Each entry is a channel-major ``RnsArray`` codeword (the output of
     ``codec.encode_array(..., channel_major=True)``, or ``host_array`` of
     residues already read back) under an arbitrary hashable key — the
-    serve engine uses request ids for monolithic slot rows and physical
-    page ids for the paged pool, where ONE stored codeword covers every
-    reader of a shared page: corrupt it and every reader's verify fails;
+    serve engine uses physical page ids of the paged pool, where ONE
+    stored codeword covers every reader of a shared page: corrupt it and every reader's verify fails;
     repair it once and every reader re-verifies.  ``matches`` compares on
     the host, so two host codewords take no device op; ``repair`` and
     ``corrupt`` leave the entry's residues on the host.

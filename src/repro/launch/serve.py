@@ -30,11 +30,11 @@ config) and ``--no-smoke`` (run the full published config, weights held in
 the compute dtype ``cfg.dtype``) are an explicit pair over one setting —
 exactly one applies, and the help text of each names the default.
 
-``--page-size N`` switches the engine onto the paged, prefix-sharing pool
-layout (DESIGN.md §13; ``--pages`` sizes the pool, ``--no-prefix-share``
-disables admission dedup) and adds a ``paging`` block to the report:
-pages in use / shared (dedup hits) / CoW copies, and the per-page
-fingerprint verify/repair counters under ``--rns-verify``.
+The engine serves from a paged, prefix-sharing KV pool (DESIGN.md §13):
+``--page-size`` sets its page (16 tokens by default), ``--pages`` sizes
+it, and the report's ``paging`` block holds pages in use / shared (dedup
+hits) / CoW copies, and the per-page fingerprint verify/repair counters
+under ``--rns-verify``.
 
 ``--rns-verify`` arms the engine's RnsArray cache-integrity fingerprints
 (verified at every retirement); ``--inject-wire-corrupt`` additionally
@@ -365,7 +365,6 @@ def _offline_main(args, ap, cfg, params, reqs, crypto_ctx, rng,
             replicas=args.replicas, overlap=args.overlap,
             queue_size=args.queue_size, rns_verify=args.rns_verify,
             page_size=args.page_size, n_pages=args.pages,
-            prefix_share=args.prefix_share,
             crypto_slots=args.crypto_slots, crypto_ctx=crypto_ctx,
             crypto_chunk=args.crypto_chunk,
         )
@@ -472,21 +471,15 @@ def main(argv=None) -> dict:
                          "smoke shrink")
     ap.set_defaults(smoke=True)
     ap.add_argument("--slots", type=int, default=4,
-                    help="concurrent request capacity (batched cache rows)")
+                    help="concurrent request capacity (page-table rows)")
     ap.add_argument("--cache-len", type=int, default=128,
                     help="per-slot KV capacity (prompt + generated)")
     ap.add_argument("--prefill-chunk", type=int, default=32)
-    ap.add_argument("--page-size", type=int, default=None,
-                    help="switch to the paged pool layout with pages of "
-                         "this many tokens (DESIGN.md §13)")
+    ap.add_argument("--page-size", type=int, default=16,
+                    help="tokens per page of the KV pool (DESIGN.md §13)")
     ap.add_argument("--pages", type=int, default=None,
                     help="physical pages in the pool (default: full "
                          "backing for every slot plus the parking page)")
-    ap.add_argument("--no-prefix-share", dest="prefix_share",
-                    action="store_false",
-                    help="disable admission-time prompt-prefix dedup "
-                         "(paged mode; measures pure paging)")
-    ap.set_defaults(prefix_share=True)
     ap.add_argument("--requests", type=int, default=8,
                     help="synthetic workload size (ignored with --trace)")
     ap.add_argument("--trace", default=None, metavar="FILE",
@@ -522,9 +515,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--save-trace", default=None, metavar="PATH",
                     help="write the workload as a replayable JSONL trace")
     ap.add_argument("--warm-restart", default=None, metavar="DIR",
-                    help="warm-restart state dir (needs --page-size and "
-                         "--rns-verify): restore + revalidate the previous "
-                         "run's retained prefix pages before serving, and "
+                    help="warm-restart state dir (needs --rns-verify): "
+                         "restore + revalidate the previous run's "
+                         "retained prefix pages before serving, and "
                          "persist this run's pool state there afterwards "
                          "(DESIGN.md §14)")
     ap.add_argument("--mode", choices=("sim", "offline", "loadgen"),
@@ -572,14 +565,11 @@ def main(argv=None) -> dict:
                     help="profiler artifact directory (default: the "
                          "--report directory, else '.')")
     args = ap.parse_args(argv)
-    if args.warm_restart and (args.page_size is None or not args.rns_verify
-                              or not args.prefix_share):
-        ap.error("--warm-restart needs --page-size, --rns-verify, and "
-                 "prefix sharing (the persisted state IS the retained "
-                 "pages plus their RRNS fingerprints)")
+    if args.warm_restart and not args.rns_verify:
+        ap.error("--warm-restart needs --rns-verify (the persisted state "
+                 "IS the retained pages plus their RRNS fingerprints)")
     if args.mode != "sim":
-        # --page-size composes with --buckets here (the padded write
-        # barrier, DESIGN.md §13); only the sim-flavored extras stay out
+        # only the sim-flavored extras stay out of the wall-clock modes
         bad = [f for f, v in (
             ("--warm-restart", bool(args.warm_restart)),
             ("--inject-wire-corrupt", args.inject_wire_corrupt),
@@ -650,7 +640,6 @@ def main(argv=None) -> dict:
             cfg, params, n_slots=args.slots, cache_len=args.cache_len,
             prefill_chunk=args.prefill_chunk, rns_verify=args.rns_verify,
             page_size=args.page_size, n_pages=args.pages,
-            prefix_share=args.prefix_share,
             crypto_slots=args.crypto_slots, crypto_ctx=crypto_ctx,
             crypto_chunk=args.crypto_chunk,
         )
@@ -706,8 +695,7 @@ def main(argv=None) -> dict:
     }
     if engine is not None:
         report["jit_traces"] = engine.jit_cache_sizes()
-        if engine.paged:
-            report["paging"] = engine.page_stats()
+        report["paging"] = engine.page_stats()
     if crypto_done:
         report["crypto"] = _crypto_report(
             crypto_done, engine.crypto_ctx, clock_key="latency_ticks")
@@ -715,13 +703,11 @@ def main(argv=None) -> dict:
         report["profile"] = {"artifact": window.artifact,
                              "captured_steps": window.captured}
     if args.rns_verify:
-        # wire keys: rids on the monolithic path (one per retired request,
-        # still stored), page ids on the paged path (only RETAINED shared
-        # pages outlive their readers — freed pages verified at release);
-        # crypto modexps add ("crypto", rid) keys (one-shots publish none)
-        keys = (sorted(k for k in engine.wire.keys()
-                       if not isinstance(k, tuple)) if engine.paged
-                else [r.rid for r in done])
+        # wire keys: page ids (only RETAINED shared pages outlive their
+        # readers — freed pages verified at release); crypto modexps add
+        # ("crypto", rid) keys (one-shots publish none)
+        keys = sorted(k for k in engine.wire.keys()
+                      if not isinstance(k, tuple))
         keys = keys + [("crypto", r.rid) for r in crypto_done
                        if ("crypto", r.rid) in engine.wire]
         rns = {

@@ -537,12 +537,13 @@ def attn_decode_paged(
     and SCATTER to (page, offset) = (pos+i) divmod page_size through the
     table; reads GATHER the table back into a (b, n_pg*page_size, g, hd)
     logical row and reuse ``decode_attention`` unchanged.  Because
-    n_pg*page_size == cache_len, the gathered row has the same length,
-    ordering, and therefore reduction order as the monolithic layout —
-    junk in parked/unwritten pages sits behind the same NEG_INF mask that
-    hides unwritten cache zeros, so outputs are bitwise-identical to the
-    un-paged path.  No ring/quant/cross-attention support (the serve
-    engine lowers or gates those before reaching here).
+    n_pg*page_size == cache_len, the gathered row has the same length
+    and ordering as a linear cache row — junk in parked/unwritten pages
+    sits behind the same NEG_INF mask that hides unwritten cache zeros,
+    so outputs equal the linear path's up to float32 rounding (the two
+    are different compiled graphs; tests hold them to a tolerance).  No
+    ring/quant/cross-attention support (the serve engine lowers or gates
+    those before reaching here).
 
     ``valid_len``/``scratch`` (both traced int32 DATA, so one graph per
     token-shape still serves every call) implement the padded write

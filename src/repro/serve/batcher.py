@@ -1,67 +1,57 @@
-"""Continuous-batching serve engine over the sharded KV cache (DESIGN.md §12).
+"""Continuous-batching serve engine over the paged KV pool (DESIGN.md
+§12-§13).
 
 One persistent ``jax.jit`` decode step serves every in-flight request at
-once: the batch axis of the decode cache is a pool of ``n_slots`` fixed-
-capacity rows ("slots"), each row belonging to at most one request.  New
-requests are admitted into FREE rows *mid-decode* — the engine chunk-
-prefills the prompt through a fixed-shape ``extend_step`` graph, splices
-the resulting row into the batched cache with one jitted
-dynamic-update, and the very next decode step carries the newcomer along
-with every already-running stream.  Because the decode step takes per-row
-positions (a ``(n_slots,)`` vector, see models/attention.py), arrival and
-departure never change any traced shape: the engine compiles each of its
-four graphs exactly once per process, which ``jit_cache_sizes()`` exposes
-and tests/test_serve_batcher.py asserts.
+once.  The KV cache is ONE pooled buffer of fixed-size pages (every leaf
+``(L, n_pages, page_size, ...)``); a host-side ``(n_slots, n_pg)`` page
+table (``PagedScheduler``) maps each slot's logical pages to physical
+ones.  A slot is one row of that table, belonging to at most one request.
+New requests are admitted into FREE slots *mid-decode*: the prompt
+prefills straight into the pool through the slot's table row (a bucketed
+single call, or the fixed-shape chunk loop), and the very next decode
+step carries the newcomer along with every already-running stream.
+Admission deduplicates shared prompt prefixes: shared pages are
+refcounted read-only, and the first write into one triggers a
+copy-on-write through a jitted page-copy graph.  The page table and the
+per-row positions ride into the decode/extend graphs as DATA (int32 array
+arguments, never trace constants), so arrival and departure never change
+any traced shape: each graph compiles once per token width, which
+``jit_cache_sizes()`` exposes and tests/test_serve_batcher.py asserts.
 
-Slot rows are computationally independent (attention masks per-row, MoE
-dispatch is per-row, every norm/matmul is row-local), so a request's
-tokens are bitwise-identical whether it runs alone or packed against
-arbitrary co-resident traffic — the isolation invariant the batcher's
-tier-1 tests pin down.
+Slot rows are computationally independent (attention masks per row, MoE
+dispatch is per row, every norm/matmul is row-local), so a request's
+tokens do not depend on its co-resident traffic; the tier-1 tests hold
+them to a plain batch-1 greedy loop over the model (tests/conftest.py).
 
-The cache layout is exactly ``dist/sharding.cache_specs``' decode layout:
-pass ``mesh=`` and the batched cache is placed on it — slots (the batch
-axis) shard over the data axes, KV heads over "model" when divisible, and
-the GQA sequence-axis fallback applies unchanged because slots only ever
-index the batch axis.
-
-``page_size=`` switches the engine onto the PAGED pool layout
-(DESIGN.md §13): the cache becomes one pooled buffer of fixed-size pages,
-a host-side ``(n_slots, n_pg)`` page table (``PagedScheduler``) maps each
-slot's logical pages to physical ones, and admission deduplicates shared
-prompt prefixes — shared pages are refcounted read-only, the first write
-into one triggers a copy-on-write through a jitted page-copy graph.  The
-page table rides into the decode/extend graphs as DATA (an int32 array
-argument, never a trace constant), so the one-persistent-trace invariant
-carries over unchanged; prompts prefill straight into the pool through the
-table (no solo cache, no splice).
+Pass ``mesh=`` and the pool is placed on ``dist/sharding.cache_specs``'
+paged layout over it (the page axis carries the batch sharding).
 
 ``rns_verify=True`` arms the RNS integrity path: at admission the engine
-fingerprints the slot's immutable prompt region (per-layer K/V sums) and
-encodes it through an RRNS ``GradCodec`` into a typed channel-major
-``RnsArray`` wire buffer, held in a ``dist.fault.WireStore`` keyed by
-request id — or, in paged mode, by PHYSICAL PAGE, so one codeword covers
+fingerprints each new prompt page's immutable span (per-layer sums of
+every pool leaf) and encodes it through an RRNS ``GradCodec`` into a
+typed channel-major ``RnsArray`` wire buffer, held in a
+``dist.fault.WireStore`` keyed by PHYSICAL PAGE, so one codeword covers
 every reader of a shared page and is checked when the page is freed or
 evicted.  Decode traffic never writes below a slot's prompt length, so at
-retirement the recomputed fingerprint must match bitwise — any mismatch
+retirement the recomputed fingerprints must match bitwise — any mismatch
 means cross-slot clobbering.  The wire buffers themselves are
 locate-and-correct codewords: ``wire_ok`` detects a corrupted stored
 buffer via ``verify_packed`` and ``repair_wire`` rebuilds the bad channel
 in place with ``dist.fault.repair_packed`` — fault repair composed with
-serving (DESIGN.md §12).  On the paged path each publish (a prompt's new
-pages), retirement verify (the request's prompt pages) and eviction
-verify (an action list's evicted pages) is ONE call of the batched
-``_fp_pages_impl`` graph — every page's sums and RRNS encode in one
-fixed-shape program — and ONE readback; the page codewords are sliced
-and compared on the host, and the paged wire store holds them as host
-(NumPy) residues, so a verify runs no per-page device op.
+serving (DESIGN.md §12).  Each publish (a prompt's new pages), retirement
+verify (the request's prompt pages) and eviction verify (an action list's
+evicted pages) is ONE call of the batched ``_fp_pages_impl`` graph —
+every page's sums and RRNS encode in one fixed-shape program — and ONE
+readback; the page codewords are sliced and compared on the host, and the
+wire store holds them as host (NumPy) residues, so a verify runs no
+per-page device op.
 
 Profiler spans (``jax.profiler.TraceAnnotation``, free while no trace is
 recording) mark each host phase the chip waits on: ``serve.admit``
 (``try_admit``), ``serve.step`` (the LLM half of ``step``),
-``serve.write_barrier`` (a paged action list), and ``serve.fp.publish`` /
-``serve.fp.verify`` around fingerprint work, each with the number of
-RRNS ``codewords`` it encodes or checks as a stat.
+``serve.write_barrier`` (an action list of the page write barrier), and
+``serve.fp.publish`` / ``serve.fp.verify`` around fingerprint work, each
+with the number of RRNS ``codewords`` it encodes or checks as a stat.
 
 Doctest — admit, stream, retire (a 5-token prompt, 4 greedy tokens)::
 
@@ -92,8 +82,8 @@ from jax.profiler import TraceAnnotation
 from repro.dist.sharding import cache_specs, named_shardings
 from repro.models import decode_step, extend_step
 from repro.models.moe import dispatch_form
-from repro.serve.scheduler import PagedScheduler, Request, Slot, SlotScheduler
-from repro.serve.serve_step import cache_abstract, paged_pool_abstract
+from repro.serve.scheduler import PagedScheduler, Request, Slot
+from repro.serve.serve_step import paged_pool_abstract
 
 __all__ = ["ContinuousBatcher"]
 
@@ -116,22 +106,22 @@ def _masked_layer_sums(x, valid):
 
 
 def _zero_cache(abs_tree):
-    """Concrete all-zero cache matching an abstract decode-cache pytree
-    ("len" becomes the int32 scalar 0)."""
+    """Concrete all-zero state matching an abstract pytree (the pool's
+    "len" becomes the int32 scalar 0)."""
     return jax.tree_util.tree_map(
         lambda l: jnp.zeros(l.shape, l.dtype), abs_tree
     )
 
 
 class ContinuousBatcher:
-    """Slot-based continuous batching over the sharded decode cache.
+    """Slot-based continuous batching over the paged KV pool.
 
     Parameters
     ----------
     cfg, params : the model (linear-KV transformer families: dense/moe).
         Sliding-window archs are lowered to the masked full-length cache
         layout (``window_cache=False``) so every slot row is linear.
-    n_slots : rows of the batched cache = max concurrent requests.
+    n_slots : rows of the page table = max concurrent requests.
     cache_len : per-slot KV capacity; every request needs
         ``len(prompt) + max_new <= cache_len``.
     prefill_chunk : token-chunk size of the admission prefill loop — long
@@ -142,26 +132,20 @@ class ContinuousBatcher:
         compiled graph per bucket width, pre-compiled by the offline
         harness's warmup.  Prompts longer than the largest bucket fall
         back to the chunked loop (counted in ``bucket_stats()``).
-        Bitwise-identical to chunked prefill: pad positions beyond plen
-        are causally invisible and later overwritten by decode writes.
-        Composes with ``page_size``: on the paged pool the bucket is
-        chosen by the tokens LEFT to compute after the shared-prefix
-        skip, real tokens write through the ordinary page-table barrier,
-        and pad tokens scatter into a per-call scratch page that is
-        freed immediately — the padded write barrier of DESIGN.md §13.
+        The bucket is chosen by the tokens LEFT to compute after the
+        shared-prefix skip; real tokens write through the ordinary
+        page-table barrier, and pad tokens scatter into a per-call
+        scratch page that is freed immediately — the padded write
+        barrier of DESIGN.md §13.
     rns_verify : arm the RnsArray cache-integrity fingerprints.
-    mesh : optional ``jax.sharding.Mesh``; the batched cache is placed on
-        ``dist.sharding.cache_specs``' layout over it.
-    page_size : switch to the paged pool layout with pages of this many
-        tokens (must divide ``cache_len`` and align with
-        ``prefill_chunk``).  None (default) keeps the monolithic slot-row
-        cache.
-    n_pages : physical pages in the pool (paged mode only).  Defaults to
+    mesh : optional ``jax.sharding.Mesh``; the pool is placed on
+        ``dist.sharding.cache_specs``' paged layout over it.
+    page_size : tokens per page of the pool (must divide ``cache_len``
+        and align with ``prefill_chunk``).
+    n_pages : physical pages in the pool.  Defaults to
         ``1 + n_slots * (cache_len // page_size)`` — parking page plus
         full backing for every slot, i.e. zero admission deferrals; a
         smaller pool oversubscribes slots against pages.
-    prefix_share : admission-time prompt-prefix dedup via the content
-        registry (paged mode only); disable to measure pure paging.
     crypto_slots : slots of the big-integer crypto lane (DESIGN.md §15);
         0 (default) disables the second request family entirely.  With
         crypto armed, ``submit`` dispatches on the request's ``family``
@@ -178,8 +162,8 @@ class ContinuousBatcher:
                  prefill_chunk: int = 32,
                  prefill_buckets: tuple | None = None,
                  rns_verify: bool = False,
-                 mesh=None, page_size: int | None = None,
-                 n_pages: int | None = None, prefix_share: bool = True,
+                 mesh=None, page_size: int = 16,
+                 n_pages: int | None = None,
                  crypto_slots: int = 0, crypto_ctx=None,
                  crypto_chunk: int = 8):
         cfg.validate()
@@ -187,7 +171,7 @@ class ContinuousBatcher:
             raise NotImplementedError(
                 f"continuous batching needs a linear-KV transformer family "
                 f"{_SUPPORTED}, not {cfg.family!r} (SSM/hybrid state and "
-                f"encoder caches are not slot-spliceable yet)"
+                f"encoder caches do not page yet)"
             )
         if cfg.kv_quant:
             raise NotImplementedError(
@@ -218,8 +202,7 @@ class ContinuousBatcher:
         self.cfg, self.params = cfg, params
         self.prefill_chunk = C = int(prefill_chunk)
         self.rns_verify = bool(rns_verify)
-        self.paged = page_size is not None
-        self.page_size = int(page_size) if self.paged else None
+        self.page_size = ps = int(page_size)
         # extend and decode launches of a MoE model by the expert dispatch
         # form their token width selects (``models.moe.dispatch_form``)
         self.moe_launches = {"grouped": 0, "buffer": 0}
@@ -248,70 +231,54 @@ class ContinuousBatcher:
             self.bucket_pad_tokens = 0
             self.bucket_real_tokens = 0
 
-        if self.paged:
-            ps = self.page_size
-            if cache_len % ps:
-                raise ValueError(
-                    f"page_size={ps} must divide cache_len={cache_len}; "
-                    f"valid page sizes: {divisors}"
-                )
-            if ps % C and C % ps:
-                # page-aligned OR chunk-aligned prefill writes; anything
-                # else makes every chunk straddle page ownership checks
-                legal = [d for d in divisors if d % C == 0 or C % d == 0]
-                raise ValueError(
-                    f"page_size={ps} must align with prefill_chunk={C} "
-                    f"(one must divide the other); chunk-compatible page "
-                    f"sizes for cache_len={cache_len}: {legal}"
-                )
-            if ps > 512 and ps % 512:
-                raise ValueError(
-                    f"page_size={ps} beyond one flash chunk must be a "
-                    f"multiple of 512 (the pool abstract runs the chunked "
-                    f"prefill per page); nearest legal page_size: "
-                    f"{ps // 512 * 512} or {-(-ps // 512) * 512}"
-                )
-            n_pg = cache_len // ps
-            if n_pages is None:
-                n_pages = 1 + n_slots * n_pg
-            min_pages = n_pg + 2
-            if n_pages < min_pages:
-                raise ValueError(
-                    f"n_pages={n_pages} cannot guarantee admission of one "
-                    f"max-length request: cache_len={cache_len} / "
-                    f"page_size={ps} = {n_pg} logical pages, plus the "
-                    f"parking page and one page of mid-page-divergence "
-                    f"headroom; minimum n_pages: {min_pages}"
-                )
-            self.n_pages = int(n_pages)
-            self.sched = PagedScheduler(
-                n_slots, cache_len, page_size=ps, n_pages=self.n_pages,
-                prefill_chunk=C, prefix_share=prefix_share,
-                prefill_buckets=self.prefill_buckets,
+        if cache_len % ps:
+            raise ValueError(
+                f"page_size={ps} must divide cache_len={cache_len}; "
+                f"valid page sizes: {divisors}"
             )
-        else:
-            self.sched = SlotScheduler(n_slots, cache_len)
+        if ps % C and C % ps:
+            # page-aligned OR chunk-aligned prefill writes; anything
+            # else makes every chunk straddle page ownership checks
+            legal = [d for d in divisors if d % C == 0 or C % d == 0]
+            raise ValueError(
+                f"page_size={ps} must align with prefill_chunk={C} "
+                f"(one must divide the other); chunk-compatible page "
+                f"sizes for cache_len={cache_len}: {legal}"
+            )
+        if ps > 512 and ps % 512:
+            raise ValueError(
+                f"page_size={ps} beyond one flash chunk must be a "
+                f"multiple of 512 (the pool abstract runs the chunked "
+                f"prefill per page); nearest legal page_size: "
+                f"{ps // 512 * 512} or {-(-ps // 512) * 512}"
+            )
+        n_pg = cache_len // ps
+        if n_pages is None:
+            n_pages = 1 + n_slots * n_pg
+        min_pages = n_pg + 2
+        if n_pages < min_pages:
+            raise ValueError(
+                f"n_pages={n_pages} cannot guarantee admission of one "
+                f"max-length request: cache_len={cache_len} / "
+                f"page_size={ps} = {n_pg} logical pages, plus the "
+                f"parking page and one page of mid-page-divergence "
+                f"headroom; minimum n_pages: {min_pages}"
+            )
+        self.n_pages = int(n_pages)
+        self.sched = PagedScheduler(
+            n_slots, cache_len, page_size=ps, n_pages=self.n_pages,
+            prefill_chunk=C, prefill_buckets=self.prefill_buckets,
+        )
 
         params_abs = jax.tree_util.tree_map(
             lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype), params
         )
-        if self.paged:
-            pool_abs = paged_pool_abstract(
-                cfg, params_abs, self.n_pages, self.page_size
-            )
-            self._solo_zero = None
-            self.cache = _zero_cache(pool_abs)
-        else:
-            solo_abs = cache_abstract(cfg, params_abs, 1, cache_len)
-            pool_abs = cache_abstract(cfg, params_abs, n_slots, cache_len)
-            self._solo_zero = _zero_cache(solo_abs)
-            self.cache = _zero_cache(pool_abs)
+        pool_abs = paged_pool_abstract(cfg, params_abs, self.n_pages, ps)
+        self.cache = _zero_cache(pool_abs)
         self.mesh = mesh
         cache_out = rep_out = None  # jit out_shardings: unpinned
         if mesh is not None:
-            self.cache_pspecs = cache_specs(
-                pool_abs, mesh, paged_pool=self.paged
-            )
+            self.cache_pspecs = cache_specs(pool_abs, mesh, paged_pool=True)
             cache_sh = named_shardings(self.cache_pspecs, mesh)
             self.cache = jax.device_put(self.cache, cache_sh)
             # the engine owns its mesh: weights and the other device state
@@ -319,8 +286,6 @@ class ContinuousBatcher:
             # default device's copies
             rep_out = named_shardings(jax.sharding.PartitionSpec(), mesh)
             self.params = jax.device_put(params, rep_out)
-            if self._solo_zero is not None:
-                self._solo_zero = jax.device_put(self._solo_zero, rep_out)
             # every graph hands the cache back in the layout it was given:
             # an output spec the partitioner rewrote (P() for P(None, ...))
             # would key a second trace of the same width mid-run
@@ -328,44 +293,28 @@ class ContinuousBatcher:
         step_out = None if mesh is None else (rep_out, cache_out)
 
         # The engine's jitted graphs — each traces exactly once per
-        # process because every argument keeps a fixed shape across
-        # admissions, retirements, and arbitrary slot occupancy (in paged
-        # mode the page table is an int32 ARRAY argument: its contents
-        # are data, never trace constants).
-        if self.paged:
-            # valid/scratch are traced int32 DATA (the padded write
-            # barrier): the chunk loop passes valid = chunk width (all
-            # tokens through the table — chunk-grid pads included, same
-            # as ever) with the parking page as a dead scratch operand;
-            # bucketed prefill passes valid = real tokens + a live
-            # scratch page.  Either way one graph per token width.
-            self._extend_fn = jax.jit(self._extend_paged_impl,
-                                      out_shardings=step_out)
-            self._decode_fn = jax.jit(self._decode_paged_impl,
-                                      out_shardings=step_out)
-            self._copy_fn = jax.jit(self._copy_impl, out_shardings=cache_out)
-            self._insert_fn = None
-        else:
-            # monolithic: extend fills the replicated solo cache, decode
-            # and the splice return the pool
-            self._extend_fn = jax.jit(self._extend_impl,
-                                      out_shardings=rep_out)
-            self._decode_fn = jax.jit(self._decode_impl,
-                                      out_shardings=step_out)
-            self._insert_fn = jax.jit(self._insert_impl,
-                                      out_shardings=cache_out)
-            self._copy_fn = None
-        self._fp_fn = (
-            jax.jit(self._fp_pages_impl if self.paged else self._fp_impl)
-            if rns_verify else None
-        )
+        # process (per token width for the extend) because every argument
+        # keeps a fixed shape across admissions, retirements, and
+        # arbitrary slot occupancy: the page table is an int32 ARRAY
+        # argument, its contents data, never trace constants.
+        # valid/scratch are traced int32 DATA too (the padded write
+        # barrier): the chunk loop passes valid = chunk width (all tokens
+        # through the table, chunk-grid pads included) with the parking
+        # page as a dead scratch operand; bucketed prefill passes valid =
+        # real tokens + a live scratch page.
+        self._extend_fn = jax.jit(self._extend_paged_impl,
+                                  out_shardings=step_out)
+        self._decode_fn = jax.jit(self._decode_paged_impl,
+                                  out_shardings=step_out)
+        self._copy_fn = jax.jit(self._copy_impl, out_shardings=cache_out)
+        self._fp_fn = jax.jit(self._fp_pages_impl) if rns_verify else None
         if rns_verify:
             from repro.dist.fault import WireStore
             from repro.dist.grad_codec import GradCodec
 
             # world=1: fingerprints are fresh encodings, wraps=0 repairs
             self.codec = GradCodec.make(world=1, correct=True)
-            # keyed by rid (monolithic rows) / physical page (paged pool)
+            # keyed by physical page (and ("crypto", rid) for lane slots)
             self.wire = WireStore(self.codec)
             self._page_span: dict[int, int] = {}
             # physical page -> rid whose prefill published its codeword,
@@ -413,66 +362,27 @@ class ContinuousBatcher:
 
     @property
     def _wire(self) -> dict:
-        """Raw key -> RnsArray mapping of the wire store (rid-keyed on the
-        monolithic path, page-keyed on the paged path)."""
+        """Raw key -> RnsArray mapping of the wire store (physical page
+        ids, and ``("crypto", rid)`` for crypto lane slots)."""
         return self.wire.raw
 
     # ------------------------------------------------------ jitted graphs
-    def _extend_impl(self, params, cache, tokens, pos, idx):
-        """One prefill chunk (or padded bucket) into the solo cache; the
-        logits of position ``idx`` only."""
-        return extend_step(self.cfg, params, cache, tokens, pos,
-                           logit_index=idx)
-
-    def _decode_impl(self, params, cache, tokens, pos):
-        """One batched decode step + greedy sampling.  tokens: (B, 1),
-        pos: (B,) per-slot write positions."""
-        logits, cache = decode_step(self.cfg, params, cache, tokens, pos)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
-    def _insert_impl(self, batch_cache, solo_cache, slot):
-        """Splice a freshly prefilled solo cache (batch 1) into slot row
-        ``slot`` of the batched cache (one dynamic-update per leaf; the
-        scalar "len" bookkeeping leaf is left alone)."""
-        def one(b_leaf, s_leaf):
-            if getattr(b_leaf, "ndim", 0) == 0:
-                return b_leaf
-            return jax.lax.dynamic_update_slice_in_dim(
-                b_leaf, s_leaf.astype(b_leaf.dtype), slot, axis=1
-            )
-
-        return jax.tree_util.tree_map(one, batch_cache, solo_cache)
-
-    def _fp_impl(self, cache, slot, plen):
-        """Per-layer masked sums of every cache leaf over slot row
-        ``slot``'s immutable prompt region [0, plen) -> (n_leaves * L,)
-        f32 fingerprint vector."""
-        names = _fp_leaves(cache)
-        valid = (jnp.arange(cache[names[0]].shape[2]) < plen).astype(
-            jnp.float32)
-        sums = []
-        for name in names:
-            row = jax.lax.dynamic_index_in_dim(
-                cache[name], slot, axis=1, keepdims=False
-            )  # (L, S, ...)
-            sums.append(_masked_layer_sums(row, valid))
-        return jnp.concatenate(sums)
-
-    # ---------------------------------------------------- paged-pool graphs
     def _extend_paged_impl(self, params, cache, tokens, pos, idx, pages,
                            valid, scratch):
-        """Paged twin of ``_extend_impl``: the first ``valid`` tokens write
-        into the pool through the (1, n_pg) page-table row, the rest into
-        page ``scratch`` (the padded write barrier)."""
+        """One prefill chunk (or padded bucket) of one slot, the logits of
+        position ``idx`` only: the first ``valid`` tokens write into the
+        pool through the (1, n_pg) page-table row, the rest into page
+        ``scratch`` (the padded write barrier)."""
         return extend_step(self.cfg, params, cache, tokens, pos,
                            logit_index=idx, pages=pages,
                            page_size=self.page_size, valid_len=valid,
                            scratch=scratch)
 
     def _decode_paged_impl(self, params, cache, tokens, pos, pages):
-        """Paged twin of ``_decode_impl``: the (n_slots, n_pg) page table
-        routes each row's read gather and token write (models/attention.py
-        ``attn_decode_paged``)."""
+        """One batched decode step + greedy sampling.  tokens (n_slots, 1),
+        pos (n_slots,) per-slot write positions; the (n_slots, n_pg) page
+        table routes each row's read gather and token write
+        (models/attention.py ``attn_decode_paged``)."""
         logits, cache = decode_step(
             self.cfg, params, cache, tokens, pos,
             pages=pages, page_size=self.page_size,
@@ -524,7 +434,7 @@ class ContinuousBatcher:
         )
         return self.codec.encode_packed(sums.reshape(-1), channel_major=True)
 
-    # ---------------------------------------------------- paged host glue
+    # ---------------------------------------------------- pool host glue
     def _fp_width(self) -> int:
         """Codeword width of one page: a sum per layer of each leaf."""
         names = _fp_leaves(self.cache)
@@ -594,10 +504,6 @@ class ContinuousBatcher:
             or any(s.req is not None and s.req.rid == rid
                    for s in self.sched.slots)
         )
-        if not self.paged:
-            # monolithic wires are rid-keyed, so the store itself
-            # tracks in-flight and retired-undrained rids
-            held = held or rid in self.wire
         if self.crypto is not None:
             held = held or (
                 any(q.rid == rid for q in self.crypto.queue)
@@ -636,8 +542,8 @@ class ContinuousBatcher:
 
     def try_admit(self, now: float = 0.0) -> list[Slot]:
         """Admit as many queued requests as there are FREE slots; each
-        admission chunk-prefills the prompt and splices it into the
-        batched cache.  Returns the admitted slots (normally now in
+        admission prefills the prompt into the pool through the slot's
+        page-table row.  Returns the admitted slots (normally now in
         DECODE; already FREE again if the first token retired the
         request — one-token budget or instant EOS)."""
         with TraceAnnotation("serve.admit"):
@@ -653,73 +559,8 @@ class ContinuousBatcher:
         return admitted
 
     def _prefill_into(self, slot: Slot, now: float) -> None:
-        if self.paged:
-            return self._prefill_into_paged(slot, now)
-        req = slot.req
-        prompt = [int(t) for t in req.prompt]
-        plen, C = len(prompt), self.prefill_chunk
-        solo = self._solo_zero
-        bucket = self._pick_bucket(plen)
-        if bucket is not None:
-            # bucketed path: ONE padded extend call — the graph keys only
-            # on the bucket width; pad junk beyond plen-1 is causally
-            # invisible (logit_index reads the last real position) and
-            # decode writes overwrite it before it can ever be attended
-            toks = jnp.asarray(
-                [prompt + [0] * (bucket - plen)], jnp.int32
-            )
-            self._count_moe(bucket)
-            logits, solo = self._extend_fn(
-                self.params, solo, toks, jnp.int32(0), jnp.int32(plen - 1)
-            )
-            self.bucket_hits[bucket] += 1
-            self.bucket_pad_tokens += bucket - plen
-            self.bucket_real_tokens += plen
-        else:
-            n_chunks = -(-plen // C)
-            if self.prefill_buckets is not None:
-                # fallback traffic stays in the ledger: its chunk-grid
-                # pads and real tokens count like a bucket's would, so
-                # pad_overhead reflects ALL prefill traffic
-                self.bucket_fallbacks += 1
-                self.bucket_pad_tokens += n_chunks * C - plen
-                self.bucket_real_tokens += plen
-            prompt = prompt + [0] * (n_chunks * C - plen)
-            last = (plen - 1) - (n_chunks - 1) * C
-            for ci in range(n_chunks):
-                toks = jnp.asarray(
-                    [prompt[ci * C:(ci + 1) * C]], jnp.int32
-                )
-                # only the final chunk's last REAL prompt position is ever
-                # read (chunk padding beyond it is causally invisible
-                # below it); the traced index keeps the unembed to one
-                # row per call
-                idx = last if ci == n_chunks - 1 else 0
-                self._count_moe(C)
-                logits, solo = self._extend_fn(
-                    self.params, solo, toks, jnp.int32(ci * C),
-                    jnp.int32(idx)
-                )
-        first = self._first_token(logits)
-        self.cache = self._insert_fn(
-            self.cache, solo, jnp.int32(slot.index)
-        )
-        if self.rns_verify:
-            with TraceAnnotation("serve.fp.publish", codewords=1):
-                fp = self._fp_fn(
-                    self.cache, jnp.int32(slot.index), jnp.int32(plen)
-                )
-                self.wire.put(req.rid, self.codec.encode_array(
-                    fp, channel_major=True
-                ))
-        if self.sched.start_decode(slot, first, now) and self.rns_verify:
-            # instant retirement (one-token budget / immediate EOS) never
-            # reaches step()'s retirement branch — verify here instead
-            self.verify_log[req.rid] = self.verify_request(req)
-
-    def _prefill_into_paged(self, slot: Slot, now: float) -> None:
-        """Paged admission prefill: chunks write straight into the pool
-        through the slot's page-table row.  Positions below
+        """Admission prefill: chunks write straight into the pool through
+        the slot's page-table row.  Positions below
         ``slot.prefill_start`` are NOT recomputed — the scheduler mapped
         registry pages holding that shared prefix at admission; each
         chunk's write barrier (``plan_write``) allocates/CoWs the pages
@@ -789,7 +630,7 @@ class ContinuousBatcher:
         if self.rns_verify:
             self._fingerprint_prompt_pages(slot, plen)
         if self.sched.start_decode(slot, first, now):
-            self._retire_paged(req)
+            self._retire(req)
 
     def _fingerprint_prompt_pages(self, slot: Slot, plen: int) -> None:
         """Encode one RRNS codeword per prompt page of ``slot`` that does
@@ -814,8 +655,8 @@ class ContinuousBatcher:
                 self._page_pub[pid] = slot.req.rid
                 self.wire.put(pid, cw)
 
-    def _retire_paged(self, req: Request) -> None:
-        """Paged retirement: verify the request's prompt-page fingerprints
+    def _retire(self, req: Request) -> None:
+        """Retirement: verify the request's prompt-page fingerprints
         while its table row is still mapped, then release the row —
         ``'freed'`` pages drop their codewords (already verified),
         ``'retained'``/``'shared'`` pages keep them for future/current
@@ -954,26 +795,22 @@ class ContinuousBatcher:
         if not decoding:
             return crypto_retired
         with TraceAnnotation("serve.step"):
-            if self.paged:
-                # write barrier for this step's one-token writes: page-
-                # boundary crossings allocate, divergence into a shared
-                # page CoWs — all BEFORE the table snapshot rides into
-                # the decode graph
-                for slot in decoding:
-                    self._exec_actions(
-                        self.sched.plan_write(slot, slot.next_pos, 1)
-                    )
+            # write barrier for this step's one-token writes: page-
+            # boundary crossings allocate, divergence into a shared page
+            # CoWs — all BEFORE the table snapshot rides into the decode
+            # graph
+            for slot in decoding:
+                self._exec_actions(
+                    self.sched.plan_write(slot, slot.next_pos, 1)
+                )
             toks, poss = self.sched.step_rows()
             step_args = [
                 self.params,
                 self.cache,
                 jnp.asarray(toks, jnp.int32)[:, None],
                 jnp.asarray(poss, jnp.int32),
+                jnp.asarray(self.sched.table, jnp.int32),
             ]
-            if self.paged:
-                step_args.append(
-                    jnp.asarray(self.sched.table, jnp.int32)
-                )
             self._count_moe(1)
             nxt, self.cache = self._decode_fn(*step_args)
             nxt = np.asarray(nxt)
@@ -984,12 +821,7 @@ class ContinuousBatcher:
                 if self.sched.record_token(slot, int(nxt[slot.index]),
                                            now):
                     retired.append(req)
-                    if self.paged:
-                        self._retire_paged(req)
-                    elif self.rns_verify:
-                        self.verify_log[req.rid] = self.verify_request(
-                            req
-                        )
+                    self._retire(req)
         return retired + crypto_retired
 
     @property
@@ -1019,9 +851,10 @@ class ContinuousBatcher:
         """Hand back the retired requests and release the engine-held
         state keyed on them (wire buffers, verify entries).  A long-lived
         server calls this after reading each batch of results — without
-        it, retired-request state (host Request objects and, under
-        ``rns_verify``, one device RnsArray per request) accumulates for
-        the engine's lifetime."""
+        it, retired-request state (host Request objects, verify entries
+        and crypto slot codewords) accumulates for the engine's
+        lifetime.  LLM wire buffers are page-keyed and already released
+        with their pages at retirement."""
         done, self.sched.completed = self.sched.completed, []
         if self.crypto is not None:
             done = done + self.crypto.completed
@@ -1030,28 +863,22 @@ class ContinuousBatcher:
             for r in done:
                 if getattr(r, "family", "llm") == "crypto":
                     self.wire.pop(("crypto", r.rid), None)
-                elif not self.paged:
-                    # paged wires are page-keyed and already released with
-                    # their pages at retirement
-                    self.wire.pop(r.rid, None)
                 self.verify_log.pop(r.rid, None)
         return done
 
     def jit_cache_sizes(self) -> dict:
         """Compiled-graph counts per engine function — the no-retrace
-        invariant says every value stays 1 for the engine's lifetime
-        (with ``prefill_buckets`` armed, ``extend`` instead stays at the
+        invariant says every value stays at most 1 for the engine's
+        lifetime (``copy`` compiles at the first copy-on-write; with
+        ``prefill_buckets`` armed, ``extend`` instead stays at the
         number of distinct padded widths the warmup compiled: the graph
         keys on token shape, and every width is pre-compiled before
         timed traffic)."""
         sizes = {
             "decode": self._decode_fn._cache_size(),
             "extend": self._extend_fn._cache_size(),
+            "copy": self._copy_fn._cache_size(),
         }
-        if self.paged:
-            sizes["copy"] = self._copy_fn._cache_size()
-        else:
-            sizes["insert"] = self._insert_fn._cache_size()
         if self._fp_fn is not None:
             sizes["fingerprint"] = self._fp_fn._cache_size()
         if self.crypto is not None:
@@ -1064,16 +891,6 @@ class ContinuousBatcher:
                     self._crypto_fns["fp"]._cache_size()
                 )
         return sizes
-
-    def _pick_bucket(self, plen: int) -> int | None:
-        """Smallest armed bucket >= plen, or None (buckets off / prompt
-        longer than every bucket -> chunk-loop fallback)."""
-        if self.prefill_buckets is None:
-            return None
-        for b in self.prefill_buckets:
-            if b >= plen:
-                return b
-        return None
 
     def _count_moe(self, width: int) -> None:
         if self.cfg.family == "moe":
@@ -1091,9 +908,9 @@ class ContinuousBatcher:
         ``buckets`` block of the offline harness report.  Fallback
         prompts count too (their chunk-grid pads and real tokens), so
         ``pad_overhead`` covers ALL prefill traffic, not only the
-        bucketed slice.  On the paged engine "real" means the tokens the
-        extend computed — a shared prefix mapped from the registry is
-        neither padded nor recomputed, so it appears in neither term."""
+        bucketed slice.  "Real" means the tokens the extend computed — a
+        shared prefix mapped from the registry is neither padded nor
+        recomputed, so it appears in neither term."""
         if self.prefill_buckets is None:
             raise RuntimeError("engine built without prefill_buckets=")
         real = self.bucket_real_tokens
@@ -1107,11 +924,9 @@ class ContinuousBatcher:
         }
 
     def page_stats(self) -> dict:
-        """Pool / dedup / CoW counters (paged mode), plus the per-page
-        fingerprint verify/repair counters when ``rns_verify`` is armed —
-        the ``paging`` block of ``launch/serve.py --report``."""
-        if not self.paged:
-            raise RuntimeError("engine built without page_size=")
+        """Pool / dedup / CoW counters, plus the per-page fingerprint
+        verify/repair counters when ``rns_verify`` is armed — the
+        ``paging`` block of ``launch/serve.py --report``."""
         stats = self.sched.page_stats()
         if self.rns_verify:
             stats["fingerprints"] = dict(self.wire.stats)
@@ -1127,14 +942,6 @@ class ContinuousBatcher:
         joined = "".join(f"{k}={v};" for k, v in sorted(fps.items()))
         return hashlib.sha256(joined.encode()).hexdigest()[:16]
 
-    def _require_warm(self):
-        if not (self.paged and self.rns_verify
-                and self.sched.registry is not None):
-            raise RuntimeError(
-                "warm restart needs the paged engine with rns_verify=True "
-                "and prefix sharing (the persisted state IS the retained "
-                "prefix pages plus their RRNS fingerprints)")
-
     def _retained_chain(self) -> list[int]:
         """Registered retained pages with live codewords, parents before
         children (restore must adopt in this order)."""
@@ -1148,12 +955,12 @@ class ContinuousBatcher:
         return out
 
     def save_warm_state(self, state_dir: str) -> dict:
-        """Persist the paged pool for a warm restart (DESIGN.md §14): the
+        """Persist the pool for a warm restart (DESIGN.md §14): the
         pooled cache leaves, every retained page's RRNS codeword, and the
         registry chain metadata, written through the RRNS checkpoint
         format (train/checkpointer.write_step_dir) so the saved state is
         itself single-channel self-healing.  Engine must be idle."""
-        self._require_warm()
+        self._require_verify()
         if self.sched.busy:
             raise RuntimeError("cannot snapshot warm state mid-flight: "
                                "drain the engine first")
@@ -1196,7 +1003,7 @@ class ContinuousBatcher:
 
         Returns the revalidation report; raises FileNotFoundError when
         nothing restorable exists under ``state_dir``."""
-        self._require_warm()
+        self._require_verify()
         if (self.sched.busy or self.sched.alloc.in_use
                 or self.sched.alloc.retained or self.sched.registry.by_pid):
             raise RuntimeError("warm state must load into a fresh engine")
@@ -1284,12 +1091,11 @@ class ContinuousBatcher:
         """Recompute ``req``'s prompt-region fingerprints and compare
         their RNS encodings bitwise against the stored wire buffers.
 
-        Monolithic: one codeword over the slot row's [0, plen) region,
-        keyed by rid.  Paged: one codeword per mapped prompt PAGE of the
-        slot's table row (shared pages check against the original
-        publisher's codeword — the dedup dataflow of DESIGN.md §13).
-        Valid until the row/pages are reused by a later admission; the
-        engine calls this automatically at retirement.
+        One codeword per mapped prompt PAGE of the slot's table row
+        (shared pages check against the original publisher's codeword —
+        the dedup dataflow of DESIGN.md §13).  Valid until the row is
+        reused by a later admission; the engine calls this automatically
+        at retirement.
 
         Crypto-family requests verify their lane slot's immutable device
         rows (exponent bits + modulus channel constants) against the
@@ -1302,39 +1108,31 @@ class ContinuousBatcher:
                 )
                 fresh = self.codec.encode_array(fp, channel_major=True)
                 return self.wire.matches(("crypto", req.rid), fresh)
-        if self.paged:
-            pids = []
-            for lp, pid in self.sched.slot_pages(req.slot_index):
-                if lp * self.page_size >= len(req.prompt):
-                    break  # decode-region pages carry no fingerprints
-                if pid in self.wire:
-                    pids.append(pid)
-            ok = True
-            with TraceAnnotation("serve.fp.verify", codewords=len(pids)):
-                for pid, cw in zip(pids, self._page_codewords(pids)):
-                    ok &= self.wire.matches(pid, cw)
-            return ok
-        with TraceAnnotation("serve.fp.verify", codewords=1):
-            fp = self._fp_fn(
-                self.cache, jnp.int32(req.slot_index),
-                jnp.int32(len(req.prompt)),
-            )
-            fresh = self.codec.encode_array(fp, channel_major=True)
-            return self.wire.matches(req.rid, fresh)
+        pids = []
+        for lp, pid in self.sched.slot_pages(req.slot_index):
+            if lp * self.page_size >= len(req.prompt):
+                break  # decode-region pages carry no fingerprints
+            if pid in self.wire:
+                pids.append(pid)
+        ok = True
+        with TraceAnnotation("serve.fp.verify", codewords=len(pids)):
+            for pid, cw in zip(pids, self._page_codewords(pids)):
+                ok &= self.wire.matches(pid, cw)
+        return ok
 
     def wire_ok(self, key) -> bool:
         """Codeword self-consistency of one stored wire buffer (RRNS
         redundant-channel check) — detects corruption of the stored
-        fingerprint itself, without touching the cache.  ``key`` is a rid
-        on the monolithic path, a physical page id on the paged path."""
+        fingerprint itself, without touching the cache.  ``key`` is a
+        physical page id, or ``("crypto", rid)`` for a crypto lane slot."""
         self._require_verify()
         return self.wire.ok(key)
 
     def repair_wire(self, key) -> dict:
         """Locate-and-correct one stored wire buffer in place via
-        ``dist.fault.repair_packed``; returns its report dict.  On the
-        paged path a shared page's buffer is repaired ONCE and every
-        reader re-verifies against the fixed codeword."""
+        ``dist.fault.repair_packed``; returns its report dict.  A shared
+        page's buffer is repaired ONCE and every reader re-verifies
+        against the fixed codeword."""
         self._require_verify()
         return self.wire.repair(key)
 

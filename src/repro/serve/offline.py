@@ -365,10 +365,10 @@ class OfflineInference:
     ``ContinuousBatcher``; ``buckets`` arms length-bucketed single-call
     prefill, ``overlap`` routes completions through a
     ``CompletionPump`` instead of running the callback inline on the
-    driver thread.  ``page_size`` puts every replica on the paged,
-    prefix-sharing pool — buckets compose with it through the padded
-    write barrier (DESIGN.md §13), and warmup additionally pre-compiles
-    the copy-on-write graph so steady state stays retrace-free.
+    driver thread.  Every replica serves from its own paged,
+    prefix-sharing pool; buckets compose with it through the padded write
+    barrier (DESIGN.md §13), and warmup additionally pre-compiles the
+    copy-on-write graph so steady state stays retrace-free.
     """
 
     def __init__(self, cfg, params, *, n_slots: int, cache_len: int,
@@ -379,8 +379,7 @@ class OfflineInference:
                  queue_size: int = 64,
                  callback=None,
                  rns_verify: bool = False,
-                 page_size: int | None = None, n_pages: int | None = None,
-                 prefix_share: bool = True,
+                 page_size: int = 16, n_pages: int | None = None,
                  crypto_slots: int = 0, crypto_ctx=None,
                  crypto_chunk: int = 8):
         from repro.serve.batcher import ContinuousBatcher
@@ -392,7 +391,6 @@ class OfflineInference:
                 prefill_chunk=prefill_chunk, prefill_buckets=buckets,
                 rns_verify=rns_verify, mesh=mesh,
                 page_size=page_size, n_pages=n_pages,
-                prefix_share=prefix_share,
                 crypto_slots=crypto_slots, crypto_ctx=crypto_ctx,
                 crypto_chunk=crypto_chunk,
             )
@@ -447,17 +445,16 @@ class OfflineInference:
             for wi, plen in enumerate(self._warm_llm_plens()):
                 # max_new=2 reaches the decode graph (1 would retire at
                 # start_decode, before any batched step compiles).  One
-                # DISTINCT token per warmup prompt: on the paged pool an
-                # earlier warmup registers its prompt pages, and a
-                # repeated token would prefix-hit — shrinking the next
-                # prompt's real extend and silently skipping the bucket
-                # width it was meant to compile.
+                # DISTINCT token per warmup prompt: an earlier warmup
+                # registers its prompt pages, and a repeated token would
+                # prefix-hit — shrinking the next prompt's real extend and
+                # silently skipping the bucket width it was meant to
+                # compile.
                 tok = 3 + wi % (eng.cfg.vocab - 3)
                 eng.submit(Request(rid=rid, prompt=[tok] * plen, max_new=2,
                                    eos=-1))
                 rid -= 1
-            if (eng.paged and eng.sched.registry is not None
-                    and eng.prefill_chunk < eng.page_size
+            if (eng.prefill_chunk < eng.page_size
                     and eng.page_size + 2 <= self.cache_len):
                 # pre-compile the copy-on-write graph: a full-prefix
                 # re-admission of a one-page prompt re-writes the shared
@@ -636,6 +633,5 @@ class OfflineInference:
                 if agg["real_tokens"] else 0.0
             )
             report["buckets"] = agg
-        if self.engines[0].paged:
-            report["paging"] = [e.page_stats() for e in self.engines]
+        report["paging"] = [e.page_stats() for e in self.engines]
         return report

@@ -110,10 +110,11 @@ class Slot:
     req: Request | None = None
     next_pos: int = 0
     last_token: int = 0
-    # paged-pool extension (PagedScheduler; always 0 on the monolithic
-    # path): first position the admission prefill actually computes (below
-    # it the row reads shared prefix pages) and the not-yet-consumed page
-    # reservation backing this request's future writes.
+    # paged-pool extension (PagedScheduler; always 0 under a bare
+    # SlotScheduler): first position the admission prefill actually
+    # computes (below it the row reads shared prefix pages) and the
+    # not-yet-consumed page reservation backing this request's future
+    # writes.
     prefill_start: int = 0
     reserved_left: int = 0
 
@@ -467,8 +468,7 @@ class PagedScheduler(SlotScheduler):
     """
 
     def __init__(self, n_slots: int, cache_len: int, *, page_size: int,
-                 n_pages: int, prefill_chunk: int,
-                 prefix_share: bool = True, prefill_buckets=None):
+                 n_pages: int, prefill_chunk: int, prefill_buckets=None):
         super().__init__(n_slots, cache_len)
         assert cache_len % page_size == 0
         self.page_size = page_size
@@ -484,7 +484,7 @@ class PagedScheduler(SlotScheduler):
         # table into a device array each step (data, never a trace const)
         self.table = [[0] * self.n_pg for _ in range(n_slots)]
         self.alloc = PageAllocator(n_pages)
-        self.registry = PrefixRegistry(page_size) if prefix_share else None
+        self.registry = PrefixRegistry(page_size)
         self.stats = {"dedup_hits": 0, "cow_copies": 0, "deferrals": 0}
 
     # ------------------------------------------------------------ queries
@@ -518,7 +518,7 @@ class PagedScheduler(SlotScheduler):
         page for the scratch itself."""
         ps, C = self.page_size, self.prefill_chunk
         plen = len(prompt)
-        matched = self.registry.match(prompt) if self.registry else []
+        matched = self.registry.match(prompt)
         shared_cap = min(len(matched) * ps, plen - 1)
         prefill_start = (shared_cap // C) * C
         # pages that provide content below prefill_start are worth mapping;
@@ -579,8 +579,7 @@ class PagedScheduler(SlotScheduler):
             # AND its descendant chain die now (the reused pid must never
             # resurrect them); the engine verifies + drops its fingerprint
             # when it executes this action (content is still intact)
-            if self.registry is not None:
-                self.registry.drop(pid)
+            self.registry.drop(pid)
             actions.append({"op": "evict", "pid": pid})
         slot.reserved_left -= 1
         self.alloc.unreserve(1)
@@ -609,8 +608,7 @@ class PagedScheduler(SlotScheduler):
                 row[lp] = new
                 actions.append({"op": "alloc", "lp": lp, "pid": new})
                 continue
-            registered = (self.registry is not None
-                          and pid in self.registry.by_pid)
+            registered = pid in self.registry.by_pid
             if self.alloc.refcount[pid] > 1 or registered:
                 new = self._alloc_for(slot, actions)  # src is refd: safe
                 row[lp] = new
@@ -644,8 +642,6 @@ class PagedScheduler(SlotScheduler):
         registry chain nodes so later admissions can share them.  Pages
         whose content already has a registered twin (this slot recomputed
         a known prefix) are skipped — first publisher wins."""
-        if self.registry is None:
-            return
         ps = self.page_size
         row = self.table[slot.index]
         key = None
@@ -680,8 +676,7 @@ class PagedScheduler(SlotScheduler):
             if pid == 0:
                 continue
             row[lp] = 0
-            retain = (self.registry is not None
-                      and pid in self.registry.by_pid)
+            retain = pid in self.registry.by_pid
             out.append((pid, self.alloc.deref(pid, retain=retain)))
         return out
 
@@ -692,8 +687,6 @@ class PagedScheduler(SlotScheduler):
         a chain node under ``parent_key`` — exactly the state the page
         held when the old process released it.  Parents must be adopted
         before children (chain keys name the parent's physical pid)."""
-        if self.registry is None:
-            raise RuntimeError("prefix sharing disabled: nothing to adopt")
         if parent_key is not None and parent_key not in self.registry.by_pid:
             raise ValueError(
                 f"page {pid}: parent {parent_key} not adopted — restore "

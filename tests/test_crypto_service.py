@@ -1,8 +1,10 @@
 """Crypto service tests: Montgomery-over-RnsArray exactness (jnp and
 Pallas bitwise-identical), modexp == pow() across multi-limb bases, the
 engine's second request family (oracle results, fingerprint verify,
-corrupt/repair, no-retrace), the mixed-workload bitwise-isolation
-invariant, and the launcher's crypto trace family.
+corrupt/repair, no-retrace), the mixed-workload isolation invariant
+(bitwise against a crypto-free engine of the same geometry, and against
+the plain reference of ``conftest``), and the launcher's crypto trace
+family.
 
 Every assertion is differential against Python's big ints — the whole
 point of the crypto workload as a TEST program: pow()/divmod() are an
@@ -19,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 import repro  # noqa: F401
+from conftest import check_against_reference, snapshot_rows
 from repro.configs import get_config
 from repro.core import backend, rns_to_int
 from repro.core.array import Layout, RnsArray
@@ -294,21 +297,16 @@ def _llm_requests(cfg, seed=0):
     return [mk(0, 5, 8), mk(1, 11, 7), mk(2, 3, 9)]
 
 
-def _kv_row(engine, slot_index, plen, n_out):
-    end = plen + n_out - 1
-    k = np.asarray(engine.cache["k"])[:, slot_index, :end]
-    v = np.asarray(engine.cache["v"])[:, slot_index, :end]
-    return k, v
-
-
 def _staggered_run(cfg, params, crypto_reqs):
-    """The PR 5 staggered-overlap harness with crypto traffic interleaved
-    at fixed ticks: r0 streams alone, r1 joins mid-decode, then r2, with
-    crypto admissions/laddering sharing every step() tick."""
+    """The staggered-overlap harness with crypto traffic interleaved at
+    fixed ticks: r0 streams alone, r1 joins mid-decode, then r2, with
+    crypto admissions/laddering sharing every step() tick.  Returns the
+    engine, the LLM requests and their K/V rows at retirement."""
     eng = ContinuousBatcher(
         cfg, params, n_slots=3, cache_len=CACHE_LEN, prefill_chunk=CHUNK,
         crypto_slots=2, crypto_ctx=_ctx(), crypto_chunk=4,
     )
+    rows = snapshot_rows(eng)
     reqs = _llm_requests(cfg)
     eng.submit(reqs[0])
     if crypto_reqs:
@@ -326,26 +324,27 @@ def _staggered_run(cfg, params, crypto_reqs):
     while eng.busy:
         eng.try_admit()
         eng.step()
-    return eng, reqs
+    return eng, reqs, rows
 
 
 def test_mixed_workload_llm_bitwise_identical(cfg, params):
     """Crypto co-residency must be bitwise-invisible to the LLM lane:
     tokens AND the full KV trajectories of every request equal the
-    crypto-free run's — and the crypto results equal a crypto-only run's
-    (isolation holds in both directions)."""
+    crypto-free run's (the same compiled graphs on the same inputs), and
+    both hold to the plain reference; the crypto results equal a
+    crypto-only run's (isolation holds in both directions)."""
     crypto_reqs, oracle = _crypto_reqs(_ctx())
-    mixed, mreqs = _staggered_run(cfg, params, crypto_reqs)
-    plain, preqs = _staggered_run(cfg, params, [])
+    mixed, mreqs, m_rows = _staggered_run(cfg, params, crypto_reqs)
+    plain, preqs, p_rows = _staggered_run(cfg, params, [])
     m_out = {r.rid: list(r.out) for r in mixed.sched.completed}
     p_out = {r.rid: list(r.out) for r in plain.sched.completed}
     assert m_out == p_out and sorted(m_out) == [0, 1, 2]
-    for mr, pr in zip(sorted(mreqs, key=lambda r: r.rid),
-                      sorted(preqs, key=lambda r: r.rid)):
-        mk, mv = _kv_row(mixed, mr.slot_index, len(mr.prompt), len(mr.out))
-        pk, pv = _kv_row(plain, pr.slot_index, len(pr.prompt), len(pr.out))
-        np.testing.assert_array_equal(mk, pk)
-        np.testing.assert_array_equal(mv, pv)
+    assert sorted(m_rows) == sorted(p_rows) == [0, 1, 2]
+    for r in mreqs:
+        for name in ("k", "v"):
+            np.testing.assert_array_equal(m_rows[r.rid][name],
+                                          p_rows[r.rid][name])
+        check_against_reference(cfg, params, r, m_rows[r.rid])
     # crypto side: same results as a crypto-only engine (and the oracle)
     solo = _crypto_engine(cfg, params, n_slots=3)
     solo_reqs, _ = _crypto_reqs(solo.crypto_ctx)

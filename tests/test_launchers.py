@@ -36,7 +36,9 @@ def test_serve_driver_runs(tmp_path):
         "--report", str(tmp_path / "report.json"),
     ])
     assert report["requests"] == 4 and report["tokens_out"] == 16
-    assert report["jit_traces"] == {"decode": 1, "extend": 1, "insert": 1}
+    # unshared prompts never copy-on-write: the copy graph never compiles
+    assert report["jit_traces"] == {"decode": 1, "extend": 1, "copy": 0}
+    assert report["paging"]["pages_in_use"] == 0
     # the saved trace replays to the identical deterministic tick metrics
     replay = serve_main([
         "--arch", "gemma-2b", "--trace", trace, "--slots", "2",
@@ -126,8 +128,7 @@ def test_serve_driver_loadgen_mode(tmp_path):
 def test_serve_driver_mode_flag_validation():
     from repro.launch.serve import main as serve_main
 
-    # --page-size composes with offline/loadgen now (padded write
-    # barrier); the sim-only extras still do not
+    # the sim-only extras stay out of offline/loadgen
     with pytest.raises(SystemExit):
         serve_main(["--mode", "offline", "--page-size", "8",
                     "--rns-verify", "--warm-restart", "/tmp/nope"])

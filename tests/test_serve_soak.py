@@ -1,28 +1,37 @@
-"""Randomized differential soak: paged+bucketed vs monolithic chunk loop.
+"""Randomized differential soak: bucketed over a small pool vs the chunk
+loop over a full one, both held to the plain reference.
 
 Each seeded round generates a mixed LLM+crypto workload whose prompt
 lengths straddle the bucket AND page boundaries (7/8/9, 15/16/17,
 23/24), shares a one-page system prefix across a random subset, gives a
-third of the requests a mid-stream EOS (timed from a probe run so it
+third of the requests a mid-stream EOS (timed from the reference so it
 fires at a real sampled token), and replays the identical trace through
 two engines:
 
-* the paged, prefix-sharing pool with bucketed single-call prefill
-  (padded write barrier) and per-page RNS fingerprints, over a pool
-  small enough that admissions defer and retained pages get evicted;
-* the monolithic chunk-loop engine with whole-row fingerprints.
+* bucketed single-call prefill (padded write barrier) and per-page RNS
+  fingerprints, over a pool small enough that admissions defer and
+  retained pages get evicted;
+* the chunk loop over a pool that backs every slot in full.
 
-Tokens and every request's logical KV rows (snapshotted at retirement)
-must match bitwise, crypto results must match exactly AND the python
-oracle, and both engines' fingerprint verifies must come back clean.
-One small seed runs in tier-1; the bigger seeds are ``-m slow`` (the CI
-soak job).
+Each engine's tokens must equal the plain batch-1 reference's
+(``conftest.reference``) and every request's logical KV rows
+(snapshotted at retirement) must agree with it within the stated float32
+tolerance; crypto results must match exactly AND the python oracle, and
+both engines' fingerprint verifies must come back clean.  One small seed
+runs in tier-1; the bigger seeds are ``-m slow`` (the CI soak job).
 """
 import numpy as np
 import pytest
 
 import repro  # noqa: F401
-from conftest import CACHE_LEN, N_PG, make_engine, run_with_row_snapshots
+from conftest import (
+    CACHE_LEN,
+    N_PG,
+    check_against_reference,
+    make_engine,
+    reference,
+    run_with_row_snapshots,
+)
 from repro.serve.crypto import CryptoContext, CryptoRequest
 from repro.serve.scheduler import Request
 
@@ -51,17 +60,12 @@ def _workload(cfg, seed, n):
 
 
 def _time_eos(cfg, params, specs, seed):
-    """Probe run (monolithic, no verify) to pick REAL mid-stream tokens
-    as EOS for ~1/3 of the requests — early retirement then lands at a
-    token both engines genuinely sample, at staggered depths."""
-    probe = make_engine(cfg, params)
-    for s in specs:
-        probe.submit(Request(rid=s["rid"], prompt=list(s["prompt"]),
-                             max_new=s["max_new"], eos=-1))
-    out = {r.rid: r.out for r in probe.run_to_completion()}
+    """Reference runs pick REAL mid-stream tokens as EOS for ~1/3 of the
+    requests — early retirement then lands at a token both engines
+    genuinely sample, at staggered depths."""
     rng = np.random.default_rng(seed + 999)
     for s in specs:
-        toks = out[s["rid"]]
+        toks, _ = reference(cfg, params, s["prompt"], s["max_new"])
         if len(toks) > 2 and rng.random() < 0.35:
             s["eos"] = int(toks[int(rng.integers(1, len(toks) - 1))])
 
@@ -84,7 +88,7 @@ def _run_differential(cfg, params, seed, n):
                        max_new=s["max_new"], eos=s["eos"]) for s in specs]
         return llm + _crypto_reqs()
 
-    eng_b = make_engine(cfg, params, paged=True, n_pages=N_PG + 4,
+    eng_b = make_engine(cfg, params, n_pages=N_PG + 4,
                         prefill_buckets=BUCKETS, rns_verify=True,
                         crypto_slots=2, crypto_ctx=ctx)
     done_b, rows_b = run_with_row_snapshots(eng_b, mk_reqs())
@@ -96,9 +100,8 @@ def _run_differential(cfg, params, seed, n):
     llm_rids = [s["rid"] for s in specs]
     for rid in llm_rids:
         assert done_b[rid].out == done_c[rid].out, f"rid {rid} tokens"
-        (bk, bv), (ck, cv) = rows_b[rid], rows_c[rid]
-        np.testing.assert_array_equal(bk, ck, err_msg=f"rid {rid} K")
-        np.testing.assert_array_equal(bv, cv, err_msg=f"rid {rid} V")
+        check_against_reference(cfg, params, done_b[rid], rows_b[rid])
+        check_against_reference(cfg, params, done_c[rid], rows_c[rid])
     # crypto lane: engines agree with each other AND the python oracle
     for cr in _crypto_reqs():
         want = (pow(cr.a, cr.b, cr.n) if cr.op == "modexp"
@@ -110,7 +113,7 @@ def _run_differential(cfg, params, seed, n):
     assert set(llm_rids) <= set(eng_b.verify_log)
     assert all(eng_b.verify_log.values())
     assert all(eng_c.verify_log.values())
-    # the paged side actually exercised its machinery this round
+    # the bucketed side actually exercised its machinery this round
     st = eng_b.bucket_stats()
     assert sum(st["hits"].values()) > 0 and st["fallbacks"] == 0
     pg = eng_b.page_stats()

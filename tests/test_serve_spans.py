@@ -31,8 +31,8 @@ def _requests():
 
 
 def _engine(cfg, params):
-    return make_engine(cfg, params, paged=True, n_slots=2,
-                       n_pages=N_PG + 2, rns_verify=True, buckets=BUCKETS)
+    return make_engine(cfg, params, n_slots=2, n_pages=N_PG + 2,
+                       rns_verify=True, buckets=BUCKETS)
 
 
 def _host_events(logdir):
@@ -111,8 +111,8 @@ def test_codewords_match_the_wire_counts(traced):
 
 
 def test_one_fingerprint_graph_call_per_paged_span(traced):
-    """On the paged path each ``serve.fp.*`` span makes ONE call of the
-    batched fingerprint graph, whatever its number of codewords."""
+    """Each LLM ``serve.fp.*`` span makes ONE call of the batched
+    fingerprint graph, whatever its number of codewords."""
     spans = (_spans(traced, "serve.fp.publish")
              + _spans(traced, "serve.fp.verify"))
     assert max(st["codewords"] for *_, st in spans) > 1
@@ -150,8 +150,10 @@ def test_one_named_extend_graph_per_bucket_width(traced):
 
 def test_monolithic_and_crypto_codewords_match_the_wire_counts(
         cfg, params, tmp_path):
-    """The monolithic row codeword and the crypto lane's slot codeword
-    are published and verified under the same spans, one each."""
+    """The prompt-page codewords of an engine with a crypto lane and the
+    lane's slot codeword are published and verified under the same
+    spans: every codeword put is counted once by a publish span and once
+    by a verify span."""
     from repro.serve.crypto import CryptoRequest
 
     eng = make_engine(cfg, params, n_slots=2, rns_verify=True,
@@ -170,9 +172,10 @@ def test_monolithic_and_crypto_codewords_match_the_wire_counts(
     codewords = {"serve.fp.publish": 0, "serve.fp.verify": 0}
     for name, *_, st in events:
         codewords[name] += st["codewords"]
-    assert len(puts) == 3
+    # 24 and 8 prompt tokens fill 3 + 1 pages of 8; one modexp slot
+    assert len(puts) == 3 + 1 + 1 and ("crypto", 9) in puts
     assert codewords["serve.fp.publish"] == len(puts)
-    assert codewords["serve.fp.verify"] == eng.wire.stats["verified"] == 3
+    assert codewords["serve.fp.verify"] == eng.wire.stats["verified"] == 5
     by_rid = {r.rid: r for r in done}
     assert set(by_rid) == {0, 1, 9}
     assert by_rid[9].result == pow(777, 4321, 1000003)
