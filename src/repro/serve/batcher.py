@@ -3,20 +3,21 @@
 
 One persistent ``jax.jit`` decode step serves every in-flight request at
 once.  The KV cache is ONE pooled buffer of fixed-size pages (every leaf
-``(L, n_pages, page_size, ...)``); a host-side ``(n_slots, n_pg)`` page
-table (``PagedScheduler``) maps each slot's logical pages to physical
-ones.  A slot is one row of that table, belonging to at most one request.
-New requests are admitted into FREE slots *mid-decode*: the prompt
-prefills straight into the pool through the slot's table row (a bucketed
-single call, or the fixed-shape chunk loop), and the very next decode
-step carries the newcomer along with every already-running stream.
+``(L, n_pages, page_size, ...)``); a host-side ``(n_slots, n_pg)`` int32
+NumPy page table (``PagedScheduler``) maps each slot's logical pages to
+physical ones.  A slot is one row of that table, belonging to at most one
+request.  New requests are admitted into FREE slots *mid-decode*: the
+prompt prefills straight into the pool through the slot's table row (a
+bucketed single call, or the fixed-shape chunk loop), and the very next
+decode step carries the newcomer along with every already-running stream.
 Admission deduplicates shared prompt prefixes: shared pages are
 refcounted read-only, and the first write into one triggers a
 copy-on-write through a jitted page-copy graph.  The page table and the
 per-row positions ride into the decode/extend graphs as DATA (int32 array
-arguments, never trace constants), so arrival and departure never change
-any traced shape: each graph compiles once per token width, which
-``jit_cache_sizes()`` exposes and tests/test_serve_batcher.py asserts.
+arguments, copied from the host each call, never trace constants), so
+arrival and departure never change any traced shape: each graph compiles
+once per token width, which ``jit_cache_sizes()`` exposes and
+tests/test_serve_batcher.py asserts.
 
 Slot rows are computationally independent (attention masks per row, MoE
 dispatch is per row, every norm/matmul is row-local), so a request's
@@ -584,11 +585,9 @@ class ContinuousBatcher:
             self._exec_actions(self.sched.plan_write(slot, start, need))
             scratch, acts = self.sched.alloc_scratch(slot)
             self._exec_actions(acts)
-            pages_row = jnp.asarray(
-                [self.sched.table[slot.index]], jnp.int32
-            )
-            toks = jnp.asarray(
-                [prompt[start:] + [0] * (bucket - need)], jnp.int32
+            pages_row = self._table_row(slot)
+            toks = np.asarray(
+                [prompt[start:] + [0] * (bucket - need)], np.int32
             )
             self._count_moe(bucket)
             logits, self.cache = self._extend_fn(
@@ -611,10 +610,8 @@ class ContinuousBatcher:
             for ci in range(n_chunks):
                 s0 = start + ci * C
                 self._exec_actions(self.sched.plan_write(slot, s0, C))
-                pages_row = jnp.asarray(
-                    [self.sched.table[slot.index]], jnp.int32
-                )
-                toks = jnp.asarray([padded[s0:s0 + C]], jnp.int32)
+                pages_row = self._table_row(slot)
+                toks = np.asarray([padded[s0:s0 + C]], np.int32)
                 idx = last if ci == n_chunks - 1 else 0
                 # chunk-grid pads keep writing THROUGH the table (their
                 # pages are reserved for this slot's decode span anyway):
@@ -631,6 +628,12 @@ class ContinuousBatcher:
             self._fingerprint_prompt_pages(slot, plen)
         if self.sched.start_decode(slot, first, now):
             self._retire(req)
+
+    def _table_row(self, slot: Slot) -> np.ndarray:
+        """The slot's (1, n_pg) page-table row as a copy: a NumPy buffer
+        handed to a jitted call may be read without a copy (CPU), and the
+        scheduler rewrites the table while the call may still run."""
+        return self.sched.table[slot.index:slot.index + 1].copy()
 
     def _fingerprint_prompt_pages(self, slot: Slot, plen: int) -> None:
         """Encode one RRNS codeword per prompt page of ``slot`` that does
@@ -803,23 +806,24 @@ class ContinuousBatcher:
                 self._exec_actions(
                     self.sched.plan_write(slot, slot.next_pos, 1)
                 )
+            # the inputs built on the host as int32 arrays and uploaded
+            # in one transfer; the table goes as a copy, since the next
+            # barrier and retirement rewrite it while the step may run
             toks, poss = self.sched.step_rows()
-            step_args = [
-                self.params,
-                self.cache,
-                jnp.asarray(toks, jnp.int32)[:, None],
-                jnp.asarray(poss, jnp.int32),
-                jnp.asarray(self.sched.table, jnp.int32),
-            ]
+            toks, poss, pages = jax.device_put((
+                np.asarray(toks, np.int32)[:, None],
+                np.asarray(poss, np.int32),
+                self.sched.table.copy(),
+            ))
             self._count_moe(1)
-            nxt, self.cache = self._decode_fn(*step_args)
-            nxt = np.asarray(nxt)
+            nxt, self.cache = self._decode_fn(self.params, self.cache,
+                                              toks, poss, pages)
+            nxt = np.asarray(nxt).tolist()
             retired = []
             for slot in decoding:
                 self.sched.advance(slot)
                 req = slot.req
-                if self.sched.record_token(slot, int(nxt[slot.index]),
-                                           now):
+                if self.sched.record_token(slot, nxt[slot.index], now):
                     retired.append(req)
                     self._retire(req)
         return retired + crypto_retired

@@ -1,10 +1,11 @@
 """Slot scheduler for the continuous-batching serve engine (DESIGN.md §12)
 and the paged-pool extension on top of it (DESIGN.md §13).
 
-Pure host-side bookkeeping — no JAX here.  The engine (serve/batcher.py)
-owns the device arrays; this module owns the request queue and the per-slot
-state machine that decides which row of the batched KV cache belongs to
-which request at every decode step:
+Pure host-side bookkeeping — no JAX here (the paged table is one NumPy
+array).  The engine (serve/batcher.py) owns the device arrays; this
+module owns the request queue and the per-slot state machine that
+decides which row of the batched KV cache belongs to which request at
+every decode step:
 
     FREE ──admit_next()──> PREFILL ──start_decode()──> DECODE
       ^                                                   │
@@ -58,6 +59,8 @@ from __future__ import annotations
 
 import dataclasses
 from collections import OrderedDict, deque
+
+import numpy as np
 
 __all__ = [
     "FREE", "PREFILL", "DECODE", "Request", "Slot", "SlotScheduler",
@@ -450,8 +453,9 @@ class PagedScheduler(SlotScheduler):
     """Slot scheduler over a PAGED physical pool (DESIGN.md §13).
 
     Extends the FREE/PREFILL/DECODE machine with the page-table layer: a
-    host-side ``(n_slots, n_pg)`` int32 table maps each slot's logical
-    pages to physical pages of the pooled cache buffer, and admission
+    host-side ``(n_slots, n_pg)`` int32 NumPy array, ``table``, maps each
+    slot's logical pages to physical pages of the pooled cache buffer
+    (page 0, the parking page, where unmapped), and admission
     deduplicates shared prompt prefixes through ``PrefixRegistry`` —
     shared pages are refcounted read-only; the first write into one
     (divergence mid-page) triggers a copy-on-write.
@@ -480,9 +484,10 @@ class PagedScheduler(SlotScheduler):
         # disagree about the scratch page
         self.prefill_buckets = (tuple(prefill_buckets)
                                 if prefill_buckets else None)
-        # numpy-free on purpose: plain host ints; the engine snapshots the
-        # table into a device array each step (data, never a trace const)
-        self.table = [[0] * self.n_pg for _ in range(n_slots)]
+        # one int32 array, written in place; the engine hands each graph
+        # a copy (data, never a trace const).  Page ids leave this class
+        # as Python ints: they key dicts and reach the warm-state JSON
+        self.table = np.zeros((n_slots, self.n_pg), np.int32)
         self.alloc = PageAllocator(n_pages)
         self.registry = PrefixRegistry(page_size)
         self.stats = {"dedup_hits": 0, "cow_copies": 0, "deferrals": 0}
@@ -490,8 +495,8 @@ class PagedScheduler(SlotScheduler):
     # ------------------------------------------------------------ queries
     def slot_pages(self, slot_index: int) -> list[tuple[int, int]]:
         """Mapped (logical_page, physical_page) pairs of one slot row."""
-        return [(lp, pid) for lp, pid in enumerate(self.table[slot_index])
-                if pid != 0]
+        return [(lp, pid) for lp, pid in
+                enumerate(self.table[slot_index].tolist()) if pid != 0]
 
     # ---------------------------------------------------------- admission
     def bucket_for(self, n: int):
@@ -561,7 +566,7 @@ class PagedScheduler(SlotScheduler):
         req.slot_index, req.t_admit = slot.index, now
         for j, pid in enumerate(mapped):
             self.alloc.ref(pid)
-            self.table[slot.index][j] = pid
+            self.table[slot.index, j] = pid
             self.stats["dedup_hits"] += 1
         self.alloc.reserve(n_reserve)
         return slot
@@ -602,7 +607,7 @@ class PagedScheduler(SlotScheduler):
         ps = self.page_size
         row = self.table[slot.index]
         for lp in range(start // ps, (start + n - 1) // ps + 1):
-            pid = row[lp]
+            pid = int(row[lp])
             if pid == 0:
                 new = self._alloc_for(slot, actions)
                 row[lp] = new
@@ -651,7 +656,7 @@ class PagedScheduler(SlotScheduler):
             if hit is not None:
                 key = hit
                 continue
-            pid = row[j]
+            pid = int(row[j])
             if self.alloc.refcount[pid] != 1 or pid in self.registry.by_pid:
                 break  # not exclusively ours to publish — stop the chain
             self.registry.add(key, toks, pid)
@@ -671,11 +676,9 @@ class PagedScheduler(SlotScheduler):
         slot.reserved_left, slot.prefill_start = 0, 0
         out = []
         row = self.table[slot_index]
-        for lp in range(self.n_pg):
-            pid = row[lp]
-            if pid == 0:
-                continue
-            row[lp] = 0
+        pids = row[row != 0].tolist()
+        row[:] = 0
+        for pid in pids:
             retain = pid in self.registry.by_pid
             out.append((pid, self.alloc.deref(pid, retain=retain)))
         return out

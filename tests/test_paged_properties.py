@@ -12,9 +12,10 @@ arrays — through random operation interleavings:
   decode / early-EOS retirement / warm-restart adoption on a pool small
   enough to force deferrals and evictions, checking global invariants
   after every step: page conservation (free / retained / referenced
-  partition the pool), refcounts equal table mappings, registered pages
-  are never free (no resurrected pid), and every registered page with
-  no readers is parked in the retained LRU.
+  partition the pool), refcounts equal table mappings, the table stays
+  one ``(n_slots, n_pg)`` int32 array that hands out Python-int page
+  ids, registered pages are never free (no resurrected pid), and every
+  registered page with no readers is parked in the retained LRU.
 
 Requires the optional ``hypothesis`` dev dependency (requirements-dev
 .txt); skips cleanly when absent.  The CI ``soak`` job raises the
@@ -23,6 +24,7 @@ example budget via ``HYPOTHESIS_PROFILE=soak``.
 import os
 from collections import Counter
 
+import numpy as np
 import pytest
 
 pytest.importorskip(
@@ -233,7 +235,8 @@ class PagedServeMachine(RuleBasedStateMachine):
         req = self.s.completed[-1]
         assert req.rid not in self.retired  # no resurrected request
         self.retired.add(req.rid)
-        self.s.release_pages(slot_index)
+        released = self.s.release_pages(slot_index)
+        assert all(type(pid) is int for pid, _ in released)
 
     @invariant()
     def pool_is_conserved(self):
@@ -257,6 +260,16 @@ class PagedServeMachine(RuleBasedStateMachine):
         cnt = Counter(pid for row in self.s.table for pid in row if pid)
         for p in range(1, self.s.alloc.n_pages):
             assert self.s.alloc.refcount[p] == cnt.get(p, 0), f"page {p}"
+
+    @invariant()
+    def table_is_one_int32_array(self):
+        t = self.s.table
+        assert isinstance(t, np.ndarray) and t.dtype == np.int32
+        assert t.shape == (3, self.CACHE_LEN // self.PAGE)
+        for slot in self.s.slots:
+            pages = self.s.slot_pages(slot.index)
+            assert all(type(lp) is int and type(p) is int
+                       for lp, p in pages)
 
     @invariant()
     def registry_and_retention_agree(self):

@@ -562,3 +562,173 @@ def test_scheduler_deferral_is_pure_host_logic():
     admitted = s.admit_next()  # pages back -> queue head admits
     assert admitted is not None
     assert admitted.index == a.index  # ...into the actually-released slot
+
+
+# ----------------------------------------- the page table and its edges
+def _assert_one_int32_table(eng, table):
+    assert eng.sched.table is table  # one table, written in place
+    assert isinstance(table, np.ndarray)
+    assert table.shape == (eng.sched.n_slots, N_PG)
+    assert table.dtype == np.int32
+
+
+def test_table_is_one_int32_array_through_admit_step_retire(cfg, params):
+    """The page table stays one ``(n_slots, n_pg)`` int32 array through
+    admissions (shared-prefix dedup included), write barriers and
+    retirements; a free slot's row is all parking page."""
+    eng = _engine(cfg, params, buckets=(8, 16, 32), rns_verify=True)
+    table = eng.sched.table
+    _assert_one_int32_table(eng, table)
+    assert not table.any()
+    for r in _prefix_reqs(cfg, 5, plen=19, shared=16, max_new=5, seed=13):
+        eng.submit(r)
+    mapped = False
+    while eng.busy:
+        eng.try_admit()
+        _assert_one_int32_table(eng, table)
+        mapped |= bool(table.any())
+        if eng.sched.decoding_slots():
+            eng.step()
+        _assert_one_int32_table(eng, table)
+        for slot in eng.sched.slots:
+            if slot.state == FREE:
+                assert not table[slot.index].any(), slot.index
+    assert mapped and not table.any()
+    assert eng.page_stats()["dedup_hits"] > 0
+    assert len(eng.sched.completed) == 5
+
+
+def _all_ints(actions):
+    for act in actions:
+        for key in ("lp", "pid", "src", "dst"):
+            if key in act:
+                assert type(act[key]) is int, act
+
+
+def test_scheduler_hands_out_python_ints():
+    """Page ids leave the scheduler as Python ints — ``slot_pages``,
+    ``release_pages``, every ``plan_write`` action (alloc, copy-on-write,
+    eviction) and ``alloc_scratch`` — since they key the engine's dicts
+    and reach the warm-state JSON, where an ``np.int32`` fails."""
+    s = PagedScheduler(1, 32, page_size=8, n_pages=6, prefill_chunk=4,
+                       prefill_buckets=(4, 8, 16, 32))
+    prefix = list(range(1, 17))
+    ops = set()
+
+    def serve(prompt, start_writes):
+        s.submit(Request(rid=prompt[0], prompt=prompt, max_new=2, eos=-1))
+        slot = s.admit_next()
+        assert slot is not None
+        start = slot.prefill_start
+        acts = s.plan_write(slot, start, len(prompt) - start)
+        pid, acts2 = s.alloc_scratch(slot)
+        assert type(pid) is int
+        s.free_scratch(pid)
+        s.register_prompt(slot, prompt)
+        for a in acts + acts2:
+            ops.add(a["op"])
+        _all_ints(acts + acts2)
+        pages = s.slot_pages(slot.index)
+        assert pages and all(type(lp) is int and type(p) is int
+                             for lp, p in pages)
+        assert start_writes == start
+        released = s.release_pages(slot.index)
+        assert all(type(p) is int for p, _ in released)
+        assert {p for p, _ in released} == {p for _, p in pages}
+        slot.state, slot.req = FREE, None
+
+    serve(prefix + [40], 0)             # publishes two prefix pages
+    serve(list(prefix), 12)             # full-prefix hit: CoW of page 1
+    serve(list(range(60, 84)), 0)       # pool dry: evicts retained pages
+    assert ops == {"alloc", "cow", "evict"}
+
+
+def test_warm_state_round_trip_with_the_array_table(cfg, params, tmp_path):
+    """Registry pids stay Python ints with the array table, so the warm
+    state saves (its page list is JSON) and a fresh engine adopts the
+    chain, dedups a same-prefix request through it and serves the
+    reference's tokens and rows."""
+    rng = np.random.default_rng(29)
+    prefix = [int(t) for t in rng.integers(1, cfg.vocab, 2 * PAGE)]
+    eng = _engine(cfg, params, rns_verify=True)
+    for i in range(2):
+        eng.submit(Request(rid=i, prompt=prefix + [50 + i], max_new=3))
+    eng.run_to_completion()
+    reg = eng.sched.registry.by_pid
+    assert len(reg) == 2 and all(type(p) is int for p in reg)
+    assert all(par is None or type(par) is int for par, _ in reg.values())
+    saved = eng.save_warm_state(str(tmp_path))
+    assert saved["pages_saved"] == 2
+
+    fresh = _engine(cfg, params, rns_verify=True)
+    rep = fresh.load_warm_state(str(tmp_path))
+    assert rep["adopted"] == 2 and rep["dropped"] == 0
+    _assert_one_int32_table(fresh, fresh.sched.table)
+    new = Request(rid="new", prompt=prefix + [9], max_new=3)
+    done, rows = run_with_row_snapshots(fresh, [new])
+    assert fresh.page_stats()["dedup_hits"] == 2
+    assert fresh.verify_log["new"] is True
+    check_against_reference(cfg, params, done["new"], rows["new"])
+
+
+def _aligned_zeros_like(a, align=64):
+    """Zeros of ``a``'s shape and dtype in a buffer that starts on an
+    ``align``-byte boundary: the CPU backend takes such a NumPy buffer
+    into a device array without a copy, so a step handed the scheduler's
+    own table would alias it whatever size the test's table is."""
+    raw = np.zeros(a.nbytes + align, np.uint8)
+    off = -raw.ctypes.data % align
+    return raw[off:off + a.nbytes].view(a.dtype).reshape(a.shape)
+
+
+def test_step_inputs_are_snapshots_of_the_table(cfg, params):
+    """Every decode and extend call gets a page table that shares no
+    memory with the scheduler's (which the next barrier and retirement
+    rewrite while a step may still run) and still holds the values it
+    had at the call; the decode's tokens are ``(n_slots, 1)`` int32; and
+    no graph retraces after the warm-up."""
+    eng = _engine(cfg, params, buckets=(8, 16, 32))
+    assert not eng.sched.table.any()
+    eng.sched.table = table = _aligned_zeros_like(eng.sched.table)
+    calls = []
+    real = {"decode": eng._decode_fn, "extend": eng._extend_fn}
+
+    def spy(kind):
+        def call(*args):
+            pages = args[4] if kind == "decode" else args[5]
+            host = np.asarray(pages)
+            assert not np.shares_memory(host, table), kind
+            if kind == "decode":
+                toks = args[2]
+                assert toks.shape == (eng.sched.n_slots, 1)
+                assert toks.dtype == np.int32
+                assert np.array_equal(host, table)
+            else:
+                assert host.shape == (1, N_PG) and host.dtype == np.int32
+                assert any(np.array_equal(host[0], row) for row in table)
+            calls.append((kind, pages, host.copy()))
+            return real[kind](*args)
+        return call
+
+    warm = _prefix_reqs(cfg, 4, plen=19, shared=16, max_new=5, seed=17)
+    for r in warm:
+        eng.submit(r)
+    eng.run_to_completion()
+    eng.drain_completed()
+    sizes = eng.jit_cache_sizes()
+    eng._decode_fn, eng._extend_fn = spy("decode"), spy("extend")
+    try:
+        reqs = _prefix_reqs(cfg, 4, plen=19, shared=16, max_new=5, seed=19)
+        for r in reqs:
+            eng.submit(Request(rid=r.rid, prompt=list(r.prompt),
+                               max_new=r.max_new))
+        done = {r.rid: r for r in eng.run_to_completion()}
+    finally:
+        eng._decode_fn, eng._extend_fn = real["decode"], real["extend"]
+    kinds = {k for k, _, _ in calls}
+    assert kinds == {"decode", "extend"}
+    for kind, pages, host in calls:  # nothing rewrote a snapshot later
+        assert np.array_equal(np.asarray(pages), host), kind
+    assert eng.jit_cache_sizes() == sizes
+    for r in reqs:
+        check_against_reference(cfg, params, done[r.rid])
